@@ -378,14 +378,17 @@ def _pallas_call_bytes(f, *example_args, full_size: int) -> float:
             has_sub = False
             for v in eqn.params.values():
                 for sub in (v if isinstance(v, (list, tuple)) else (v,)):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
                         walk(sub.jaxpr)
                         has_sub = True
-                    elif isinstance(sub, jax.core.Jaxpr):
+                    elif isinstance(sub, jax.extend.core.Jaxpr):
                         walk(sub)
                         has_sub = True
             if eqn.primitive.name == "pallas_call":
-                calls.append(eqn)
+                # the kernel is traced twice — compiled for a TPU and
+                # interpreted elsewhere — and one of the two is lowered
+                if not eqn.params["interpret"]:
+                    calls.append(eqn)
             elif not has_sub and eqn.primitive.name != "reshape":
                 # call-like eqns (pjit, scan, ...) are accounted by their
                 # walked sub-jaxpr, not by their own result bindings
@@ -488,7 +491,7 @@ def bench_masked_step(args):
 
     print(f"# masked_step: {slots} lanes x {size}x{size}x1, T={T} "
           f"(fused kernel in "
-          f"{'interpret' if os.environ.get('REPRO_PALLAS_INTERPRET', '1') != '0' else 'compiled'}"
+          f"{'compiled' if jax.default_backend() == 'tpu' else 'interpret'}"
           f" mode — wall time only meaningful compiled)")
     print("path,bytes_accessed,us_per_call")
     print(f"jnp_masked_hlo,{bytes_jnp:.0f},{us_jnp:.0f}")

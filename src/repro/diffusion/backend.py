@@ -20,7 +20,7 @@ Registered backends:
                      of (x, eps_hat, noise) + one write.
 
 All backends agree numerically on active lanes (the Pallas kernels compute
-the identical f32 expression, modulo rsqrt-vs-divide rounding ~1e-7), and
+the identical f32 expression), and
 ``masked_step`` with ``active=ones`` is bitwise ``step`` for every backend.
 Inactive lanes always pass through bit-unchanged, even at out-of-range t.
 
@@ -37,15 +37,19 @@ Two step contracts per backend:
   so guided traffic is STILL that one program.  The dense ancestral table
   makes ``index_step`` bitwise ``step`` on the jnp backend.
 
-The Pallas backends honour ``REPRO_PALLAS_INTERPRET`` (see ``kernels/ops``):
-interpret mode on CPU, compiled Mosaic on TPU.
+The Pallas backends run compiled Mosaic where the program is lowered for a
+TPU and the Pallas interpreter elsewhere (``repro.kernels.pallas_call``).
+``get_backend(None)`` picks the platform's path: ``"pallas_masked"`` on a
+TPU, ``"jnp"`` elsewhere.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, Optional, Union
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 # Row index of the guidance-scale row in the canonical coefficient table
 # (rows 0-3 = c_eps, ar, sigma, keep drive the update; row 4 = the
@@ -150,6 +154,30 @@ class StepBackend:
                                       clip=clip)
 
 
+class LaneSharded(StepBackend):
+    """``inner``'s masked trajectory tick under ``shard_map`` over the lane
+    axis of a mesh: each device steps its own block of lanes.  XLA cannot
+    partition a Mosaic kernel, so a slot array sharded over a mesh reaches
+    the fused tick this way; lanes are independent, so no backend loses
+    anything by it.  Only :meth:`masked_index_step` (and the guided tick
+    built on it) is sharded — what the serving engine's scan window runs.
+    """
+
+    def __init__(self, inner: StepBackend, mesh, lane_axes):
+        self.inner = inner
+        self.name = inner.name
+        self.mesh = mesh
+        self.lane = P(lane_axes)
+
+    def masked_index_step(self, x, cols, eps_hat, noise, active, tables, *,
+                          clip: float = 3.0):
+        lane = self.lane
+        return jax.shard_map(
+            functools.partial(self.inner.masked_index_step, clip=clip),
+            mesh=self.mesh, in_specs=(lane,) * 5 + (P(),), out_specs=lane,
+            check_vma=False)(x, cols, eps_hat, noise, active, tables)
+
+
 def make_lane_tick(apply_fn: Callable, masked_index: Callable, kmax: int,
                    image_shape, conditional: bool = False) -> Callable:
     """Build the SCAN-COMPATIBLE masked lane tick every hot loop shares.
@@ -234,9 +262,11 @@ def register(cls):
 
 
 def get_backend(spec: BackendLike = None) -> StepBackend:
-    """Resolve a backend name (or pass an instance through).  None = "jnp"."""
+    """Resolve a backend name (or pass an instance through).  None = the
+    default platform's step path: the compiled fused tick
+    (``"pallas_masked"``) on a TPU, the jnp reference elsewhere."""
     if spec is None:
-        return _REGISTRY["jnp"]
+        spec = "pallas_masked" if jax.default_backend() == "tpu" else "jnp"
     if isinstance(spec, StepBackend):
         return spec
     try:

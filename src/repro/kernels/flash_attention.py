@@ -14,8 +14,8 @@ TPU-native design (DESIGN.md §3.3):
   skipped with ``pl.when`` (no compute issued), partially-masked blocks apply
   an iota mask.
 
-Validated on CPU via ``interpret=True`` against ``kernels/ref.py``; the same
-``pl.pallas_call`` lowers to Mosaic on TPU.
+Validated on CPU in the Pallas interpreter against ``kernels/ref.py``; the
+same kernel lowers to Mosaic on TPU (``repro.kernels.pallas_call``).
 """
 from __future__ import annotations
 
@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import pallas_call
 
 NEG_INF = -2.0 ** 30
 
@@ -96,11 +98,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_kv: int = 128,
-                    softmax_scale=None, interpret: bool = True):
+                    softmax_scale=None):
     """q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd).  Returns (B, Sq, H, hd).
-
-    ``interpret=True`` executes the kernel body in python on CPU (this
-    container); pass False on real TPU.
     """
     b, sq, h, hd = q.shape
     skv, kvh = k.shape[1], k.shape[2]
@@ -121,7 +120,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_kv=block_kv, n_kv=n_kv)
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -140,7 +139,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q * g,), jnp.float32),
             pltpu.VMEM((block_q * g, hd), jnp.float32),
         ],
-        interpret=interpret,
     )(qg, kg, vg)
     return out.reshape(b, kvh, sq, g, hd).transpose(0, 2, 1, 3, 4) \
               .reshape(b, sq, h, hd)

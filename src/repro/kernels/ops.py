@@ -1,22 +1,18 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``REPRO_PALLAS_INTERPRET=0`` switches to compiled Mosaic lowering (real TPU);
-the default (1) runs the kernel bodies in python on CPU — this container.
+Each kernel runs compiled (Mosaic) where the program is lowered for a TPU
+and in the Pallas interpreter elsewhere (``repro.kernels.pallas_call``).
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ddpm_step as _ddpm
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ssm_scan as _ssm
-
-
-def _interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -24,14 +20,13 @@ def _interpret() -> bool:
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_kv: int = 128):
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               block_q=block_q, block_kv=block_kv,
-                               interpret=_interpret())
+                               block_q=block_q, block_kv=block_kv)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "head_block"))
 def ssm_scan(x, dt, a, bm, cm, *, chunk: int = 128, head_block: int = 8):
     return _ssm.ssm_scan(x, dt, a, bm, cm, chunk=chunk,
-                         head_block=head_block, interpret=_interpret())
+                         head_block=head_block)
 
 
 @jax.jit
@@ -42,8 +37,7 @@ def ddpm_step(sched, x_t, t, eps_hat, noise):
     (a registered pytree, so it traces like any other argument).
     """
     coefs = _ddpm.ddpm_step_coefs(sched, t)
-    return _ddpm.ddpm_step(x_t, eps_hat, noise, coefs,
-                           interpret=_interpret())
+    return _ddpm.ddpm_step(x_t, eps_hat, noise, coefs)
 
 
 @functools.partial(jax.jit, static_argnames=("clip",))
@@ -56,7 +50,7 @@ def ddpm_masked_step(sched, x_t, t, eps_hat, noise, active, *,
     if tables is None:
         tables = _ddpm.masked_step_tables(sched)
     return _ddpm.ddpm_masked_step(x_t, t, eps_hat, noise, active, tables,
-                                  clip=clip, interpret=_interpret())
+                                  clip=clip)
 
 
 @functools.partial(jax.jit, static_argnames=("clip",))
@@ -67,13 +61,13 @@ def traj_masked_step(x, cols, eps_hat, noise, active, tables, *,
     ``masked_step_tables``) + update + clip + active select in ONE pallas
     program — strided DDIM and dense DDPM lanes share the kernel."""
     return _ddpm.traj_masked_step(x, cols, eps_hat, noise, active, tables,
-                                  clip=clip, interpret=_interpret())
+                                  clip=clip)
 
 
 @jax.jit
 def ddpm_index_step(x, cols, eps_hat, noise, tables):
-    """Fused trajectory step for every sample (no mask): gathers per-sample
-    (c_eps, 1/√ar, σ, keep) from the canonical table and runs the
-    :func:`ddpm_step` kernel."""
-    coefs = _ddpm.index_step_coefs(tables, cols)
-    return _ddpm.ddpm_step(x, eps_hat, noise, coefs, interpret=_interpret())
+    """Fused trajectory step for every sample (no mask, no clip): the
+    per-sample column's (c_eps, ar, σ, keep) gathered in SMEM."""
+    ones = jnp.ones((x.shape[0],), bool)
+    return _ddpm.traj_masked_step(x, cols, eps_hat, noise, ones, tables,
+                                  clip=0.0)

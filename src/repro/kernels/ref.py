@@ -62,13 +62,14 @@ def ssm_scan_ref(x, dt, a, bm, cm):
 
 
 def ddpm_step_ref(x_t, eps_hat, noise, coefs):
-    """Direct p_sample with precomputed per-sample coefs (B, 4)."""
+    """Direct p_sample with precomputed per-sample canonical coefs (B, 4)
+    = (c_eps, ar, sigma, keep)."""
     b = x_t.shape[0]
     shape = (b,) + (1,) * (x_t.ndim - 1)
     c_eps = coefs[:, 0].reshape(shape)
-    inv_sa = coefs[:, 1].reshape(shape)
+    ar = coefs[:, 1].reshape(shape)
     sigma = coefs[:, 2].reshape(shape)
     keep = coefs[:, 3].reshape(shape)
     x = x_t.astype(jnp.float32)
-    mean = (x - c_eps * eps_hat.astype(jnp.float32)) * inv_sa
+    mean = (x - c_eps * eps_hat.astype(jnp.float32)) / jnp.sqrt(ar)
     return (mean + keep * sigma * noise.astype(jnp.float32)).astype(x_t.dtype)

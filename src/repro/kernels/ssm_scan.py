@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_call
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
                 chunk: int):
@@ -65,8 +67,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
     state_ref[...] = state * jnp.exp(cum[l - 1])[:, None, None] + upd
 
 
-def ssm_scan(x, dt, a, bm, cm, *, chunk: int = 128, head_block: int = 8,
-             interpret: bool = True):
+def ssm_scan(x, dt, a, bm, cm, *, chunk: int = 128, head_block: int = 8):
     """Chunked SSD scan.
 
     x: (B, S, nh, P) head inputs; dt: (B, S, nh) softplus'd step sizes;
@@ -81,7 +82,7 @@ def ssm_scan(x, dt, a, bm, cm, *, chunk: int = 128, head_block: int = 8,
     assert s % chunk == 0 and nh % head_block == 0
     grid = (b, nh // head_block, s // chunk)
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -97,5 +98,4 @@ def ssm_scan(x, dt, a, bm, cm, *, chunk: int = 128, head_block: int = 8,
                                lambda ib, ih, ic: (ib, ic, ih, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, nh, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((head_block, n, p), jnp.float32)],
-        interpret=interpret,
     )(x, dt, a, bm, cm)

@@ -51,7 +51,7 @@ def _parse_args(argv=None):
 
 def main(argv=None):
     args = _parse_args(argv)
-    from repro.launch.mesh import host_mesh, mesh_context
+    from repro.launch.mesh import host_mesh
     mesh = host_mesh(args.mesh_shape, force_devices=args.devices)
 
     import dataclasses
@@ -89,7 +89,9 @@ def main(argv=None):
     records = []
     print("n_clients,round_s,server_gflops,client_gflops,server_loss,"
           "speedup_vs_looped")
-    with mesh_context(mesh):
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+    with jax.set_mesh(mesh):
         for n in args.clients:
             cfg = TrainerConfig(n_clients=n, T=args.T,
                                 cut_ratio=args.cut_ratio,

@@ -1,26 +1,39 @@
-"""Production mesh construction (TPU v5e pods; 256 chips/pod).
+"""Mesh construction and the per-chip peaks of the devices the repo targets.
 
-A FUNCTION (not module-level) so importing never touches jax device state.
+Functions (not module-level state), so importing never touches jax device
+state.
 """
 from __future__ import annotations
 
 import os
 
 import jax
+from jax.sharding import AxisType
 
-# TPU v5e hardware constants used by the roofline (per chip)
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # B/s
-ICI_BW = 50e9                     # B/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip
+# interconnect (``ici_bw`` is that over the chip's 4 links, per link).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9,
+                    "hbm_bytes": 16e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The per-chip peaks of ``device_kind``.  A device missing from
+    :data:`PEAKS` is an error: no number is assumed for it."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the jax version has them
-    (jax.sharding.AxisType landed after 0.4.x; older versions default to
-    Auto semantics under jit anyway)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    kwargs = {"axis_types": (at.Auto,) * len(shape)} if at is not None else {}
-    return jax.make_mesh(shape, axes, **kwargs)
+    """``jax.make_mesh`` with every axis Auto (the partitioner places
+    whatever the sharding rules leave open)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,14 +45,6 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_demo_mesh(data: int = 2, model: int = 4):
     """Small mesh for sharding tests (requires forced host devices)."""
     return make_mesh((data, model), ("data", "model"))
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh`` where available (newer jax); on older versions the
-    Mesh object is itself the context manager that sets the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
 
 
 def force_host_devices(n: int) -> None:

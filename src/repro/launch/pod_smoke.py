@@ -194,9 +194,6 @@ def main(argv=None):
     import jax
     if args.num_processes > 1:
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        # admission/refill bookkeeping runs eagerly on globally-sharded
-        # slot arrays between jitted windows
-        jax.config.update("jax_spmd_mode", "allow_all")
         jax.distributed.initialize(coordinator_address=args.coordinator,
                                    num_processes=args.num_processes,
                                    process_id=args.process_id)

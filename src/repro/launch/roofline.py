@@ -22,7 +22,10 @@ import re
 from typing import Dict
 
 from repro.configs.base import InputShape, ModelConfig
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import peaks
+
+# the dry run plans for a v5e pod (``mesh.make_production_mesh``)
+_V5E = peaks("TPU v5 lite")
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -217,12 +220,12 @@ def roofline_terms(cfg: ModelConfig, shape: InputShape, *, n_chips: int,
     a_flops = analytic_flops(cfg, shape, window)
     m_flops = model_flops(cfg, shape)
     a_bytes = analytic_hbm_bytes(cfg, shape, window, n_chips)
-    compute_s = a_flops / (n_chips * PEAK_FLOPS_BF16)
-    compute_hlo_s = hlo_flops / (n_chips * PEAK_FLOPS_BF16)
+    compute_s = a_flops / (n_chips * _V5E["flops_bf16"])
+    compute_hlo_s = hlo_flops / (n_chips * _V5E["flops_bf16"])
     # hlo_bytes is per-device (post-SPMD program) -> per-chip time directly
-    memory_s = hlo_bytes / HBM_BW
-    memory_analytic_s = a_bytes / (n_chips * HBM_BW)
-    collective_s = link_bytes / ICI_BW     # per-device link bytes
+    memory_s = hlo_bytes / _V5E["hbm_bw"]
+    memory_analytic_s = a_bytes / (n_chips * _V5E["hbm_bw"])
+    collective_s = link_bytes / _V5E["ici_bw"]     # per-device link bytes
     terms = {
         "compute_s": compute_s,
         "compute_hlo_s": compute_hlo_s,

@@ -33,7 +33,7 @@ def _parse_args(argv=None):
 
 def main(argv=None):
     args = _parse_args(argv)
-    from repro.launch.mesh import host_mesh, mesh_context
+    from repro.launch.mesh import host_mesh
     mesh = host_mesh(args.mesh_shape, force_devices=args.devices)
 
     import time
@@ -58,7 +58,9 @@ def main(argv=None):
           f"(window={args.window or 'full'})")
 
     key = jax.random.PRNGKey(args.seed)
-    with mesh_context(mesh):
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+    with jax.set_mesh(mesh):
         params = tf.init_params(key, cfg)
         p_shard = shd.to_shardings(shd.param_specs(params, ctx), mesh)
         params = jax.device_put(params, p_shard)

@@ -4,11 +4,17 @@
 Runs the CollaFuse server segment for a stream of generation requests
 (mixed cut-ratios / batch sizes / arrival ticks) through ONE jitted masked
 denoise step per tick, with the slot array sharded over ``data`` and the
-U-Net sharded via ``parallel/sharding.py``.  On this CPU container use
+U-Net sharded via ``parallel/sharding.py``.  On a CPU host use
 ``--devices N`` to force N host devices::
 
     PYTHONPATH=src python -m repro.launch.serve_diffusion --devices 4 \
         --mesh-shape 4x1 --slots 16 --requests 32 --image 8 --T 20
+
+``--model paper`` serves the paper's U-Net (``configs/paper_unet``) at its
+published widths — the configuration for a TPU::
+
+    PYTHONPATH=src python -m repro.launch.serve_diffusion --model paper \
+        --T 100 --mix --slots 32 --requests 8
 
 ``--compare-sequential`` also times the per-request ``split_sample``
 baseline and prints the continuous-batching speedup.
@@ -23,7 +29,13 @@ def _parse_args(argv=None):
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--max-batch", type=int, default=2,
                     help="request batch sizes cycle 1..max-batch")
-    ap.add_argument("--image", type=int, default=8)
+    ap.add_argument("--model", choices=["toy", "paper"], default="toy",
+                    help="toy = a 2-level, 8-channel U-Net at --image "
+                         "(CPU runs); paper = configs/paper_unet at its "
+                         "published widths (128x128x1, 64 base channels, "
+                         "mults 1/2/4/8, attention at 16x16)")
+    ap.add_argument("--image", type=int, default=8,
+                    help="image side of the toy model")
     ap.add_argument("--T", type=int, default=20)
     ap.add_argument("--cut-ratios", type=float, nargs="+",
                     default=[0.25, 0.5, 0.75])
@@ -31,11 +43,13 @@ def _parse_args(argv=None):
                     help="private client models finishing t_split..1")
     ap.add_argument("--policy", choices=["fifo", "cut_ratio"],
                     default="cut_ratio")
-    ap.add_argument("--step-backend", default="jnp",
+    ap.add_argument("--step-backend", default=None,
                     choices=["jnp", "pallas", "pallas_masked"],
                     help="denoise-tick StepBackend; pallas_masked fuses the "
-                         "whole masked tick into one kernel (interpret mode "
-                         "unless REPRO_PALLAS_INTERPRET=0)")
+                         "whole masked tick into one kernel (compiled on a "
+                         "TPU, interpreted elsewhere).  Default: the "
+                         "platform's path — pallas_masked on a TPU, jnp "
+                         "elsewhere")
     ap.add_argument("--sampler", default="ddpm", choices=["ddpm", "ddim"],
                     help="trajectory/update family requests walk: ddpm = "
                          "dense T-step chain; ddim = strided --num-steps "
@@ -134,16 +148,28 @@ def _parse_args(argv=None):
     return ap.parse_args(argv)
 
 
-def main(argv=None):
-    args = _parse_args(argv)
-    from repro.launch.mesh import host_mesh, mesh_context
-    mesh = host_mesh(args.mesh_shape, force_devices=args.devices)
-
+def unet_config(args):
+    """The served U-Net: the paper's (``--model paper``) or the CPU toy."""
     import dataclasses
 
+    from repro.configs.base import UNetConfig
+    from repro.configs.paper_unet import CONFIG
+    if args.model == "paper":
+        return dataclasses.replace(CONFIG, num_classes=args.num_classes)
+    return dataclasses.replace(
+        UNetConfig().reduced(), image_size=args.image, base_channels=8,
+        channel_mults=(1, 2), n_res_blocks=1, attn_resolutions=(),
+        time_dim=32, norm_groups=4, num_classes=args.num_classes)
+
+
+def build_engine(args, mesh):
+    """Everything :func:`main` serves, built from its arguments: returns
+    ``(engine, requests, client_stack, dyn_sampler)``.  ``mesh``
+    None keeps the slot state on the default device.  Weights come from
+    ``unet.init_params`` under ``--seed``; no file is read."""
     import jax
 
-    from repro.configs.base import UNetConfig
+    from repro.diffusion.backend import get_backend
     from repro.diffusion.sampler import make_sampler
     from repro.diffusion.schedule import cosine_schedule
     from repro.models import unet
@@ -151,9 +177,8 @@ def main(argv=None):
     from repro.optim import adamw
     from repro.parallel import sharding as shd
     from repro.serve import (EngineConfig, Request, ServeEngine,
-                             make_scheduler, time_sequential)
+                             make_scheduler)
 
-    d, m = mesh.shape["data"], mesh.shape["model"]
     if args.sampler == "ddpm" and args.num_steps:
         raise SystemExit("--num-steps strides the chain, which needs "
                          "--sampler ddim (ddpm is dense-only)")
@@ -180,17 +205,20 @@ def main(argv=None):
                                              else [])
     traffic = ("mix of " + "/".join(request_samplers) if args.mix
                else samplers[request_samplers[0]].describe())
-    print(f"serve_diffusion: mesh=data:{d}xmodel:{m} slots={args.slots} "
+    d, m = (mesh.shape["data"], mesh.shape["model"]) if mesh is not None \
+        else (1, 1)
+    ucfg = unet_config(args)
+    image_shape = (ucfg.image_size, ucfg.image_size, ucfg.in_channels)
+    print(f"serve_diffusion: model={args.model} "
+          f"image={'x'.join(map(str, image_shape))} "
+          f"mesh=data:{d}xmodel:{m} slots={args.slots} "
           f"requests={args.requests} T={args.T} policy={args.policy} "
-          f"backend={args.step_backend} sampler={traffic} "
+          f"backend={get_backend(args.step_backend).name} "
+          f"sampler={traffic} "
           f"pack={args.pack} spare_columns={args.spare_columns} "
           f"min_kid={args.min_kid} guidance={args.guidance} "
           f"num_classes={args.num_classes}")
 
-    ucfg = dataclasses.replace(
-        UNetConfig().reduced(), image_size=args.image, base_channels=8,
-        channel_mults=(1, 2), n_res_blocks=1, attn_resolutions=(),
-        time_dim=32, norm_groups=4, num_classes=args.num_classes)
     if args.num_classes > 0:
         apply_fn = lambda p, x, t, y=None: unet.forward(p, x, t, ucfg, y)
     else:
@@ -199,63 +227,78 @@ def main(argv=None):
 
     key = jax.random.PRNGKey(args.seed)
     k_s, k_c, k_r = jax.random.split(key, 3)
-    ctx = ShardCtx(mesh=mesh, batch_axes=("data",))
-    with mesh_context(mesh):
-        server_params = unet.init_params(k_s, ucfg)
+    server_params = unet.init_params(k_s, ucfg)
+    if mesh is not None:
+        ctx = ShardCtx(mesh=mesh, batch_axes=("data",))
         server_params = jax.device_put(
             server_params,
             shd.to_shardings(shd.param_specs(server_params, ctx), mesh))
-        client_stack = adamw.tree_stack(
-            [unet.init_params(k, ucfg)
-             for k in jax.random.split(k_c, args.clients)])
+    client_stack = adamw.tree_stack(
+        [unet.init_params(k, ucfg)
+         for k in jax.random.split(k_c, args.clients)])
 
-        requests = [
-            Request(req_id=i, key=jax.random.fold_in(k_r, i),
-                    batch=1 + i % args.max_batch,
-                    cut_ratio=args.cut_ratios[i % len(args.cut_ratios)],
-                    client_idx=i % args.clients,
-                    arrival_tick=i * args.arrival_every,
-                    sampler=request_samplers[i % len(request_samplers)],
-                    label=(i % args.num_classes) if args.num_classes
-                          else 0)
-            for i in range(args.requests)
-        ]
+    requests = [
+        Request(req_id=i, key=jax.random.fold_in(k_r, i),
+                batch=1 + i % args.max_batch,
+                cut_ratio=args.cut_ratios[i % len(args.cut_ratios)],
+                client_idx=i % args.clients,
+                arrival_tick=i * args.arrival_every,
+                sampler=request_samplers[i % len(request_samplers)],
+                label=(i % args.num_classes) if args.num_classes
+                      else 0)
+        for i in range(args.requests)
+    ]
 
-        admission = None
-        if args.min_kid is not None:
-            from repro.data.synthetic import (ClientDataConfig,
-                                              make_client_datasets)
-            from repro.serve import AdmissionPolicy
-            calib_sets, _ = make_client_datasets(ClientDataConfig(
-                n_clients=1, per_client=args.calib, image_size=args.image,
-                holdout=2, seed=args.seed))
-            admission = AdmissionPolicy(sched, calib_sets[0],
-                                        min_kid=args.min_kid,
-                                        samplers=samplers)
-        obs = None
-        if args.trace_out or args.metrics_out or args.profile_dir:
-            from repro.serve import ObsConfig
-            obs = ObsConfig(
-                trace_path=args.trace_out or None,
-                metrics_path=args.metrics_out or None,
-                metrics_every=args.metrics_every,
-                profile_dir=args.profile_dir or None,
-                profile_windows=args.profile_windows)
-        cfg = EngineConfig(
-            sched=sched, apply_fn=apply_fn,
-            image_shape=(args.image, args.image, 1), slots=args.slots,
-            scheduler=make_scheduler(args.policy, args.T, samplers=samplers,
-                                     pack=args.pack),
-            step_backend=args.step_backend, mesh=mesh, samplers=samplers,
-            admission=admission, spare_columns=args.spare_columns,
-            ticks_per_dispatch=args.ticks_per_dispatch,
-            async_depth=args.async_depth, finish_mode=args.finish_mode,
-            finish_async_depth=args.finish_async_depth, obs=obs,
-            num_classes=args.num_classes)
-        eng = ServeEngine(cfg, server_params)
-        if dyn_sampler is not None:
-            eng.register_sampler("dyn", dyn_sampler)
+    admission = None
+    if args.min_kid is not None:
+        from repro.data.synthetic import (ClientDataConfig,
+                                          make_client_datasets)
+        from repro.serve import AdmissionPolicy
+        calib_sets, _ = make_client_datasets(ClientDataConfig(
+            n_clients=1, per_client=args.calib,
+            image_size=ucfg.image_size, holdout=2, seed=args.seed))
+        admission = AdmissionPolicy(sched, calib_sets[0],
+                                    min_kid=args.min_kid,
+                                    samplers=samplers)
+    obs = None
+    if args.trace_out or args.metrics_out or args.profile_dir:
+        from repro.serve import ObsConfig
+        obs = ObsConfig(
+            trace_path=args.trace_out or None,
+            metrics_path=args.metrics_out or None,
+            metrics_every=args.metrics_every,
+            profile_dir=args.profile_dir or None,
+            profile_windows=args.profile_windows)
+    cfg = EngineConfig(
+        sched=sched, apply_fn=apply_fn, image_shape=image_shape,
+        slots=args.slots,
+        scheduler=make_scheduler(args.policy, args.T, samplers=samplers,
+                                 pack=args.pack),
+        step_backend=args.step_backend, mesh=mesh, samplers=samplers,
+        admission=admission, spare_columns=args.spare_columns,
+        ticks_per_dispatch=args.ticks_per_dispatch,
+        async_depth=args.async_depth, finish_mode=args.finish_mode,
+        finish_async_depth=args.finish_async_depth, obs=obs,
+        num_classes=args.num_classes)
+    eng = ServeEngine(cfg, server_params)
+    if dyn_sampler is not None:
+        eng.register_sampler("dyn", dyn_sampler)
+    return eng, requests, client_stack, dyn_sampler
 
+
+def main(argv=None):
+    args = _parse_args(argv)
+    from repro.launch.mesh import host_mesh
+    mesh = host_mesh(args.mesh_shape, force_devices=args.devices)
+
+    import jax
+
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.serve import time_sequential
+
+    use_compile_cache()
+    with jax.set_mesh(mesh):
+        eng, requests, client_stack, dyn_sampler = build_engine(args, mesh)
         eng.serve(list(requests), client_stack)            # compile + warmup
         n_compiled = eng._tick._cache_size()
         if dyn_sampler is not None:
@@ -288,7 +331,7 @@ def main(argv=None):
             print(f"slot pool (pack={args.pack}): fragmentation_frac "
                   f"{s['fragmentation_frac']:.4f} | occupancy by class "
                   f"(lane-ticks): {top}", flush=True)
-        if admission is not None:
+        if eng.admission is not None:
             a = s["admission"]
             dk = a.get("disclosure_kid", {})
             print(f"admission (min_kid={args.min_kid}): "
@@ -303,7 +346,7 @@ def main(argv=None):
                 jax.numpy.isfinite(jax.numpy.asarray(comp.x0)).all()), \
                 f"non-finite output for request {comp.request.req_id}"
 
-        if obs is not None and res.timelines:
+        if eng.obs and res.timelines:
             rid = min(res.timelines)
             print(f"request {rid} lifecycle: " + " -> ".join(
                 f"{e['stage']}@t{e['tick']}" if "tick" in e else e["stage"]
@@ -315,7 +358,7 @@ def main(argv=None):
             print(f"wrote metrics {args.metrics_out}")
 
         if args.compare_sequential:
-            seq_s = time_sequential(cfg, requests, server_params,
+            seq_s = time_sequential(eng.config, requests, eng.server_params,
                                     client_stack)
             s["sequential_s"] = seq_s
             s["speedup_vs_sequential"] = seq_s / res.wall_s

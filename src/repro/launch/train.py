@@ -39,7 +39,7 @@ def _parse_args(argv=None):
 
 def main(argv=None):
     args = _parse_args(argv)
-    from repro.launch.mesh import host_mesh, mesh_context
+    from repro.launch.mesh import host_mesh
     mesh = host_mesh(args.mesh_shape, force_devices=args.devices)
 
     import time
@@ -64,7 +64,9 @@ def main(argv=None):
           f"fsdp={args.fsdp}")
 
     key = jax.random.PRNGKey(0)
-    with mesh_context(mesh):
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+    with jax.set_mesh(mesh):
         params = tf.init_params(key, cfg)
         opt_cfg = adamw.AdamWConfig(lr=args.lr)
         opt = adamw.init_state(params, opt_cfg)

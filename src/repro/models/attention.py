@@ -199,11 +199,11 @@ def qshard_attention(q, k, v, ctx: ShardCtx, *, causal: bool = True,
         off = idx * (sq // n)
         return _blockwise_dyn(qs, ks, vs, off, causal=causal, window=window)
 
-    from repro.models.layers import shard_map_compat
-    return shard_map_compat(
+    # the body uses axis_index, which the replication checker can't type
+    return jax.shard_map(
         local, mesh=ctx.mesh,
         in_specs=(P(bs, axis), P(bs), P(bs)),
-        out_specs=P(bs, axis))(q, k, v)
+        out_specs=P(bs, axis), check_vma=False)(q, k, v)
 
 
 def decode_attention(q, k_cache, v_cache, valid_len=None,

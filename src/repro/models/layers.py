@@ -13,18 +13,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map (new) / jax.experimental.shard_map (0.4.x), with the
-    replication check disabled under whichever kwarg this version spells
-    (the bodies here use axis_index, which the checker can't type)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # Sharding context
 # ---------------------------------------------------------------------------

@@ -30,7 +30,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import ShardCtx, dense_init, split_keys
-from repro.models.layers import shard_map_compat as _shard_map
 
 
 def moe_init(key, cfg: ModelConfig, dtype=jnp.float32):
@@ -211,7 +210,7 @@ def moe_forward(x, p, cfg: ModelConfig, ctx: ShardCtx) -> Tuple[jax.Array, jax.A
         shards_all = ctx.data_size * ep
         if n_tok % shards_all == 0 and n_tok // shards_all >= ep:
             # big-batch path: tokens sharded over (batch, model), all_to_all EP
-            body = _shard_map(
+            body = jax.shard_map(
                 lambda xf, rw, wg, wu, wd: _moe_ep_body(
                     xf, rw, wg, wu, wd, cfg=cfg, ep=ep,
                     model_axis=ctx.model_axis),
@@ -221,7 +220,8 @@ def moe_forward(x, p, cfg: ModelConfig, ctx: ShardCtx) -> Tuple[jax.Array, jax.A
                           P(ctx.model_axis, None, None),
                           P(ctx.model_axis, None, None),
                           P(ctx.model_axis, None, None)),
-                out_specs=(P((*ctx.batch_axes, ctx.model_axis), None), P()))
+                out_specs=(P((*ctx.batch_axes, ctx.model_axis), None), P()),
+                check_vma=False)
         else:
             # decode path: tokens sharded over batch axes when divisible
             # (replicated over model); fully replicated for tiny batches
@@ -235,7 +235,7 @@ def moe_forward(x, p, cfg: ModelConfig, ctx: ShardCtx) -> Tuple[jax.Array, jax.A
                         else ctx.batch_axes[0])
             if n_tok % ctx.data_size != 0:
                 tok_spec = None
-            body = _shard_map(
+            body = jax.shard_map(
                 repl_body,
                 mesh=ctx.mesh,
                 in_specs=(P(tok_spec, None),
@@ -243,7 +243,8 @@ def moe_forward(x, p, cfg: ModelConfig, ctx: ShardCtx) -> Tuple[jax.Array, jax.A
                           P(ctx.model_axis, None, None),
                           P(ctx.model_axis, None, None),
                           P(ctx.model_axis, None, None)),
-                out_specs=(P(tok_spec, None), P()))
+                out_specs=(P(tok_spec, None), P()),
+                check_vma=False)
         out, aux = body(x_flat, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     if "shared" in p:
         out = out + _shared_expert(x_flat, p["shared"])

@@ -75,7 +75,8 @@ import numpy as np
 from repro.core import collafuse
 from repro.core.collafuse import CutPlan
 from repro.diffusion.backend import (GUIDANCE_ROW, N_TABLE_ROWS, BackendLike,
-                                     get_backend, make_lane_tick)
+                                     LaneSharded, get_backend,
+                                     make_lane_tick)
 from repro.diffusion.sampler import Sampler, assert_same_menu, default_samplers
 from repro.diffusion.schedule import DiffusionSchedule
 from repro.obs import NULL_OBS, Observability, ObsConfig, resolve_obs
@@ -622,6 +623,7 @@ class ServeEngine:
         self._lane_tick = make_lane_tick(
             self.apply_fn, self._masked_index, kmax, self.image_shape,
             conditional=self._conditional)
+        self._window_tick = self._lane_tick
         # per-request key derivation, jitted per batch size: the eager
         # vmapped fold_in/split trace costs ~5ms per ADMISSION, which at
         # pod scale (hundreds of in-flight requests) would dwarf the
@@ -645,6 +647,16 @@ class ServeEngine:
                 shd.slot_specs(jax.eval_shape(self._init_state), ctx),
                 cfg.mesh)
             self._done_sharding = shd.gathered_sharding(cfg.mesh)
+            # the window's step runs per device on its block of lanes: XLA
+            # cannot partition the fused kernel (the finisher's lanes are
+            # not sharded and keep the plain tick)
+            sharded = LaneSharded(self.backend, cfg.mesh,
+                                  self._slot_shardings["x"].spec[0])
+            self._window_tick = make_lane_tick(
+                self.apply_fn,
+                functools.partial(sharded.guided_masked_index_step,
+                                  clip=self.clip),
+                kmax, self.image_shape, conditional=self._conditional)
         # async_depth > 1 holds window N's x/done refs while window N+1
         # computes, so the slot state cannot be donated to the dispatch;
         # the synchronous depth keeps the old zero-copy behaviour
@@ -652,19 +664,6 @@ class ServeEngine:
         self._tick = jax.jit(self._make_tick(), donate_argnums=donate)
         self._finish = jax.jit(self._make_finish())
         self._admit_prog = jax.jit(self._make_admit())
-        # The client segment is a DIFFERENT party's compute in CollaFuse,
-        # so when this process exposes more than one local device (and the
-        # slot state is unsharded) finish batches dispatch onto the LAST
-        # device: client programs get their own execution queue.  On a
-        # single device XLA runs programs serially, so a multi-ms finish
-        # program would head-of-line block every eager admit/retire op
-        # queued behind it and streaming would only convert device-idle
-        # time into host stalls.
-        self._finish_device = None
-        if cfg.mesh is None:
-            local = jax.local_devices()
-            if len(local) > 1:
-                self._finish_device = local[-1]
         self._stack_cache: Dict[tuple, tuple] = {}  # see _gather_stack
 
     # ------------------------------------------------------------------
@@ -704,7 +703,7 @@ class ServeEngine:
 
         def window(state, params, menu):
             def body(st, _):
-                x, pos, key, done = self._lane_tick(
+                x, pos, key, done = self._window_tick(
                     params, menu, st["x"], st["pos"], st["key"], st["end"],
                     st["traj"], st["active"], st["y"], st["pair"],
                     st["cond"])
@@ -1498,11 +1497,6 @@ class ServeEngine:
                 keys[ci, j] = comp.k_cli[i]
                 valid[ci, j] = True
                 placement.append((comp, i, ci, j))
-        # the cached stack is COMMITTED to the finish device (when one
-        # exists), which alone pins this jit call to the client device's
-        # own queue — the numpy lane operands follow it, with no
-        # per-wave eager device_put chain; CPU→CPU placement does not
-        # change numerics, so stream ≡ drain holds
         x0_ref = self._finish(stack_used, self._menu, x, pos, end, traj,
                               keys, valid)
         return x0_ref, placement
@@ -1510,16 +1504,13 @@ class ServeEngine:
     def _gather_stack(self, client_stack, present: tuple):
         """The compacted client param stack for one ``present`` set,
         cached — streamed waves hit the same set every dispatch, and the
-        eager gather (plus the hop to the finish device) is pure host
-        overhead on the hot path.  The cache entry pins the source stack
+        eager gather is pure host overhead on the hot path.  The cache entry pins the source stack
         so an ``id()`` reuse after GC can never alias a stale gather."""
         hit = self._stack_cache.get((id(client_stack), present))
         if hit is not None and hit[0] is client_stack:
             return hit[1]
         idx = jnp.asarray(list(present))
         gathered = jax.tree.map(lambda a: a[idx], client_stack)
-        if self._finish_device is not None:
-            gathered = jax.device_put(gathered, self._finish_device)
         self._stack_cache[(id(client_stack), present)] = (client_stack,
                                                           gathered)
         return gathered

@@ -21,7 +21,7 @@ SCRIPT = textwrap.dedent("""
     from repro.configs import get_config
     from repro.models import transformer as tf, attention as attn
     from repro.models.layers import ShardCtx
-    from repro.launch.mesh import make_demo_mesh, mesh_context
+    from repro.launch.mesh import make_demo_mesh
     from repro.parallel import sharding as shd
 
     mesh = make_demo_mesh(2, 4)
@@ -32,7 +32,7 @@ SCRIPT = textwrap.dedent("""
     q = jax.random.normal(ks[0], (b, s, h, hd))
     k = jax.random.normal(ks[1], (b, s, kv, hd))
     v = jax.random.normal(ks[2], (b, s, kv, hd))
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         for w in (0, 24):
             o_ref = attn.blockwise_attention(q, k, v, causal=True, window=w)
             o_qs = attn.qshard_attention(q, k, v, ctx_qs, causal=True,
@@ -46,7 +46,7 @@ SCRIPT = textwrap.dedent("""
                               cfg.vocab_size)
     ref, _ = tf.forward(params, {"tokens": toks}, cfg)
     ctx_cs = ShardCtx(mesh=mesh, batch_axes=("data",), cache_seq_shard=True)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         cache = tf.init_cache(cfg, 4, 16)
         cache = jax.device_put(
             cache, shd.to_shardings(shd.cache_specs(cache, ctx_cs), mesh))
