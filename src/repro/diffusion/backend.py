@@ -226,23 +226,32 @@ def make_lane_tick(apply_fn: Callable, masked_index: Callable, kmax: int,
     window), ``lax.fori_loop`` (the client finisher) or calls it
     directly.  ``conditional`` engines call ``apply_fn(params, x, t, y)``;
     unconditional ones keep the classic 3-arg convention.
+
+    The model call, the noise draw and the step run under the named scopes
+    ``unet``, ``noise`` and ``step``: every program built on the tick
+    carries them in its ops' ``op_name`` metadata, which a profile reads
+    to split device time by layer.  Scopes are metadata only; outputs are
+    unchanged.
     """
     def lane_tick(params, menu, x, pos, key, end, traj, gate, y, pair,
                   cond):
         stepping = gate & (pos < end)
         pos_c = jnp.clip(pos, 0, kmax - 1)
         t_lane = menu["ts_pad"][traj, pos_c]  # model conditions on t
-        if conditional:
-            eps_hat = apply_fn(params, x, t_lane, y)
-        else:
-            eps_hat = apply_fn(params, x, t_lane)
-        ks = jax.vmap(jax.random.split)(key)
-        k_next, k_n = ks[:, 0], ks[:, 1]
-        noise = jax.vmap(
-            lambda k: jax.random.normal(k, image_shape, jnp.float32))(k_n)
+        with jax.named_scope("unet"):
+            if conditional:
+                eps_hat = apply_fn(params, x, t_lane, y)
+            else:
+                eps_hat = apply_fn(params, x, t_lane)
+        with jax.named_scope("noise"):
+            ks = jax.vmap(jax.random.split)(key)
+            k_next, k_n = ks[:, 0], ks[:, 1]
+            noise = jax.vmap(
+                lambda k: jax.random.normal(k, image_shape, jnp.float32))(k_n)
         cols = menu["offsets"][traj] + pos_c
-        x = masked_index(x, cols, eps_hat, noise, stepping, pair, cond,
-                         tables=menu["tables"])
+        with jax.named_scope("step"):
+            x = masked_index(x, cols, eps_hat, noise, stepping, pair, cond,
+                             tables=menu["tables"])
         pos = jnp.where(stepping, pos + 1, pos)
         key = jnp.where(stepping[:, None], k_next, key)
         done = stepping & (pos >= end)        # x now holds the cut tensor

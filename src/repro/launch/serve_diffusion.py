@@ -20,6 +20,7 @@ published widths — the configuration for a TPU::
 baseline and prints the continuous-batching speedup.
 """
 import argparse
+import contextlib
 import json
 
 
@@ -142,9 +143,9 @@ def _parse_args(argv=None):
     ap.add_argument("--metrics-every", type=int, default=1,
                     help="snapshot cadence in windows for --metrics-out")
     ap.add_argument("--profile-dir", default="",
-                    help="capture a jax.profiler trace of the first "
-                         "--profile-windows dispatches into this directory")
-    ap.add_argument("--profile-windows", type=int, default=4)
+                    help="capture a jax.profiler trace of the warm serve "
+                         "call into this directory; the host loop's spans "
+                         "are in it, on the device ops' clock")
     return ap.parse_args(argv)
 
 
@@ -266,9 +267,7 @@ def build_engine(args, mesh):
         obs = ObsConfig(
             trace_path=args.trace_out or None,
             metrics_path=args.metrics_out or None,
-            metrics_every=args.metrics_every,
-            profile_dir=args.profile_dir or None,
-            profile_windows=args.profile_windows)
+            metrics_every=args.metrics_every)
     cfg = EngineConfig(
         sched=sched, apply_fn=apply_fn, image_shape=image_shape,
         slots=args.slots,
@@ -305,7 +304,9 @@ def main(argv=None):
             # ad-hoc re-registration at the serve boundary: one device
             # scatter into the spare columns, zero new scan compiles
             eng.register_sampler("dyn", dyn_sampler)
-        res = eng.serve(list(requests), client_stack)      # warm jit cache
+        with (jax.profiler.trace(args.profile_dir) if args.profile_dir
+              else contextlib.nullcontext()):
+            res = eng.serve(list(requests), client_stack)  # warm jit cache
         if dyn_sampler is not None:
             assert eng._tick._cache_size() == n_compiled, \
                 "dynamic sampler registration recompiled the scan program"

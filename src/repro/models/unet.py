@@ -87,6 +87,7 @@ def attnblock_init(key, c, dtype=jnp.float32):
     }
 
 
+@jax.named_scope("attn")
 def attnblock(x, p, groups):
     b, h, w, c = x.shape
     qkv = conv(gn(x, p["norm"], groups), p["qkv"]).reshape(b, h * w, 3, c)

@@ -5,7 +5,9 @@ Three pillars, one facade:
 * ``trace``    — span tracer exporting Chrome trace-event JSON (Perfetto):
                  host-loop phases, trainer rounds, admission cache fills,
                  per-request async tracks; per-host ``pid`` tagging so a
-                 pod run merges into one timeline.
+                 pod run merges into one timeline.  Every span is also a
+                 ``jax.profiler.TraceAnnotation``, so a profile holds the
+                 host phases on the device ops' clock.
 * ``registry`` — typed counters/gauges/histograms with labels, snapshotted
                  to JSON-lines at window boundaries (live metrics for
                  long-lived engines).
@@ -63,8 +65,9 @@ class ObsConfig:
                        ``metrics_every`` window boundaries (JSON-lines).
     ``metrics_every``  snapshot cadence in windows.
     ``timelines``      record per-request lifecycle events.
-    ``profile_dir``    capture a ``jax.profiler`` trace of the first
-                       ``profile_windows`` dispatches into this dir.
+
+    Spans also annotate any ``jax.profiler`` capture the caller has
+    running around the call (``with jax.profiler.trace(dir): ...``).
     """
 
     trace: bool = True
@@ -72,12 +75,9 @@ class ObsConfig:
     metrics_path: Optional[str] = None
     metrics_every: int = 1
     timelines: bool = True
-    profile_dir: Optional[str] = None
-    profile_windows: int = 4
 
     def __post_init__(self):
         assert self.metrics_every >= 1, self.metrics_every
-        assert self.profile_windows >= 1, self.profile_windows
 
 
 class Observability:

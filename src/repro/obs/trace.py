@@ -9,6 +9,13 @@ trace event with microsecond timestamps; :meth:`Tracer.export` writes the
 <https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU>`_
 JSON that ``chrome://tracing`` and https://ui.perfetto.dev load directly.
 
+Each span is also a ``jax.profiler.TraceAnnotation`` named
+``<cat>.<name>`` (``serve.dispatch``, ``serve.sync_wait``, ...) for as
+long as it is open.  Under a ``jax.profiler`` capture the span lands on
+the profile's host plane, on the clock the device ops are timed on, so a
+gap in which the device sat idle reads as the host phase that held it.
+Outside a capture the annotation is a no-op.
+
 Multi-host runs tag every event with the host's ``pid`` (and a
 ``process_name`` metadata event), so concatenating the per-host event
 lists — :func:`merge_traces` — yields ONE pod timeline with a lane per
@@ -36,6 +43,8 @@ import json
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # every phase code this tracer may emit; validate_events enforces it
 _KNOWN_PHASES = frozenset("XibeCM")
 # metadata event names the spec defines (we emit the first two)
@@ -45,15 +54,18 @@ _METADATA_NAMES = frozenset({"process_name", "thread_name",
 
 
 class _Span:
-    """One open "X" span; created by :meth:`Tracer.span`."""
+    """One open "X" span and its profiler annotation; created by
+    :meth:`Tracer.span`."""
 
-    __slots__ = ("_tracer", "_event", "_t0")
+    __slots__ = ("_tracer", "_event", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", event: Dict[str, Any]):
         self._tracer = tracer
         self._event = event
+        self._ann = TraceAnnotation(f"{event['cat']}.{event['name']}")
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = self._tracer._now_us()
         return self
 
@@ -62,6 +74,7 @@ class _Span:
         ev["ts"] = self._t0
         ev["dur"] = self._tracer._now_us() - self._t0
         self._tracer._events.append(ev)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -153,7 +166,8 @@ class Tracer:
     # ------------------------------------------------------------------
     def span(self, name: str, cat: str = "serve", tid: int = 0,
              **args) -> _Span:
-        """Context manager recording one complete ("X") event."""
+        """Context manager recording one complete ("X") event, annotated
+        ``<cat>.<name>`` on the profiler's host plane while it is open."""
         return _Span(self, {"name": name, "cat": cat, "ph": "X",
                             "pid": self.pid, "tid": int(tid),
                             "args": args})

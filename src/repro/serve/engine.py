@@ -361,10 +361,10 @@ class _FinishPipeline:
                 "client_finish_dispatch", tick=now, requests=len(comps),
                 lanes=n_lanes):
             self._pending.append(
-                self._eng._pack_finish(comps, self._stack) + (now,))
+                self._eng._pack_finish(comps, self._stack, self._metrics)
+                + (now,))
         self.batches += 1
         self.lanes += n_lanes
-        self._metrics.on_finish_dispatch(len(comps), n_lanes)
 
     def _sync_oldest(self, now: int) -> None:
         x0_ref, placement, disp_tick = self._pending.popleft()
@@ -387,13 +387,14 @@ class _FinishPipeline:
         if not self._ready and not self._pending:
             return
         t0 = time.perf_counter()
-        while self._pending and _device_ready(self._pending[0][0]):
-            self._sync_oldest(now)
-        floor = self._wave_lanes // 2 if queue_drained else self._wave_lanes
-        for key in [k for k, n in self._staged.items() if n >= floor]:
-            self._dispatch(self._take_wave(key), now)
-            while len(self._pending) >= self._depth:
+        with self._eng.obs.tracer.span("finish_flush", tick=now):
+            while self._pending and _device_ready(self._pending[0][0]):
                 self._sync_oldest(now)
+            floor = self._wave_lanes // 2 if queue_drained else self._wave_lanes
+            for key in [k for k, n in self._staged.items() if n >= floor]:
+                self._dispatch(self._take_wave(key), now)
+                while len(self._pending) >= self._depth:
+                    self._sync_oldest(now)
         self.host_s += time.perf_counter() - t0
 
     def drain(self, now: int) -> None:
@@ -407,19 +408,20 @@ class _FinishPipeline:
         if not self._ready and not self._pending:
             return
         t0 = time.perf_counter()
-        rest = sorted((item for b in self._ready.values() for item in b),
-                      key=lambda sc: -sc[0])
-        self._ready.clear()
-        self._staged.clear()
-        while rest:
-            comps, lanes = [], 0
-            while rest and lanes < self._wave_lanes:
-                _, comp = rest.pop(0)
-                comps.append(comp)
-                lanes += comp.request.batch
-            self._dispatch(comps, now)
-        while self._pending:
-            self._sync_oldest(now)
+        with self._eng.obs.tracer.span("finish_drain", tick=now):
+            rest = sorted((item for b in self._ready.values() for item in b),
+                          key=lambda sc: -sc[0])
+            self._ready.clear()
+            self._staged.clear()
+            while rest:
+                comps, lanes = [], 0
+                while rest and lanes < self._wave_lanes:
+                    _, comp = rest.pop(0)
+                    comps.append(comp)
+                    lanes += comp.request.batch
+                self._dispatch(comps, now)
+            while self._pending:
+                self._sync_oldest(now)
         dt = time.perf_counter() - t0
         self.host_s += dt
         self.tail_s += dt
@@ -1082,8 +1084,9 @@ class ServeEngine:
         first = done_np.argmax(axis=0)           # first done tick per lane
         with tracer.span("retire", start_tick=start,
                          lanes=int(lanes.size)):
-            rows = self._host_rows(
-                x_ref, [ln for ln in lanes.tolist() if not lane_shadow[ln]])
+            with tracer.span("retire_rows"):
+                rows = self._host_rows(
+                    x_ref, [ln for ln in lanes.tolist() if not lane_shadow[ln]])
             for lane in lanes.tolist():
                 rec = inflight[int(lane_req[lane])]
                 if not lane_shadow[lane]:
@@ -1140,52 +1143,55 @@ class ServeEngine:
         obs = self.obs
         tracer = obs.tracer
         obs.timelines.reset()       # lifecycles are per serve() call
-        decisions: Dict[int, AdmissionDecision] = {}
-        for r in requests:
-            assert self._lanes_of(r) <= self.slots, \
-                f"request {r.req_id} needs {self._lanes_of(r)} lanes " \
-                f"(batch {r.batch}" + \
-                (", guided ×2" if self._sampler_of(r).guided else "") + \
-                f") > capacity {self.slots}"
-            self._sampler_of(r)                    # fail fast on bad names
-            obs.request(r.req_id, "queued", tick=r.arrival_tick,
-                        batch=r.batch, cut_ratio=r.cut_ratio,
-                        sampler=r.sampler)
-            d = self._decision(r)                  # cached; gate once here
-            if d is not None:
-                decisions[r.req_id] = d
-                obs.request(r.req_id, "scored", action=d.action,
-                            kid=d.kid, effective_cut=d.effective_cut)
-                if not d.served:
-                    obs.request(r.req_id, "rejected")
+        # queueing, scoring and the fresh slot array: the host work
+        # before the first admission
+        with tracer.span("enqueue", requests=len(requests)):
+            decisions: Dict[int, AdmissionDecision] = {}
+            for r in requests:
+                assert self._lanes_of(r) <= self.slots, \
+                    f"request {r.req_id} needs {self._lanes_of(r)} lanes " \
+                    f"(batch {r.batch}" + \
+                    (", guided ×2" if self._sampler_of(r).guided else "") + \
+                    f") > capacity {self.slots}"
+                self._sampler_of(r)                    # fail fast on bad names
+                obs.request(r.req_id, "queued", tick=r.arrival_tick,
+                            batch=r.batch, cut_ratio=r.cut_ratio,
+                            sampler=r.sampler)
+                d = self._decision(r)                  # cached; gate once here
+                if d is not None:
+                    decisions[r.req_id] = d
+                    obs.request(r.req_id, "scored", action=d.action,
+                                kid=d.kid, effective_cut=d.effective_cut)
+                    if not d.served:
+                        obs.request(r.req_id, "rejected")
 
-        def _served(r):
-            return r.req_id not in decisions or decisions[r.req_id].served
+            def _served(r):
+                return r.req_id not in decisions or decisions[r.req_id].served
 
-        # zero-server-step requests (cut position 0, e.g. c=1 — or bumped
-        # all the way to full concealment) complete at arrival (x_mid =
-        # x_T) without ever occupying a slot
-        local_only = collections.deque(sorted(
-            (r for r in requests
-             if _served(r) and self._effective_cut(r) == 0),
-            key=lambda r: r.arrival_tick))
-        for r in requests:
-            if not _served(r):
-                self.scheduler.add(r)   # dropped at the select gate below
-            elif self._effective_cut(r) > 0:
-                self.scheduler.add(r)
-        if max_ticks is None:
-            span = max((r.arrival_tick for r in requests), default=0)
-            total = sum(self._effective_cut(r) for r in requests
-                        if _served(r))
-            # liveness bound: serving work + per-request window overhead
-            # (a lane can idle up to k·async_depth ticks between reaching
-            # its cut and its boundary sync freeing the slot)
-            overhead = k * (self.async_depth + 1)
-            max_ticks = span + total + self._kmax + 16 + \
-                overhead * max(1, len(requests))
+            # zero-server-step requests (cut position 0, e.g. c=1 — or bumped
+            # all the way to full concealment) complete at arrival (x_mid =
+            # x_T) without ever occupying a slot
+            local_only = collections.deque(sorted(
+                (r for r in requests
+                 if _served(r) and self._effective_cut(r) == 0),
+                key=lambda r: r.arrival_tick))
+            for r in requests:
+                if not _served(r):
+                    self.scheduler.add(r)   # dropped at the select gate below
+                elif self._effective_cut(r) > 0:
+                    self.scheduler.add(r)
+            if max_ticks is None:
+                span = max((r.arrival_tick for r in requests), default=0)
+                total = sum(self._effective_cut(r) for r in requests
+                            if _served(r))
+                # liveness bound: serving work + per-request window overhead
+                # (a lane can idle up to k·async_depth ticks between reaching
+                # its cut and its boundary sync freeing the slot)
+                overhead = k * (self.async_depth + 1)
+                max_ticks = span + total + self._kmax + 16 + \
+                    overhead * max(1, len(requests))
 
-        state = self._init_state()
+            state = self._init_state()
         lane_req = np.full(self.slots, -1, np.int64)
         lane_img = np.full(self.slots, -1, np.int64)
         lane_shadow = np.zeros(self.slots, bool)   # uncond halves of pairs
@@ -1199,13 +1205,10 @@ class ServeEngine:
         metrics = ServeMetrics(self.slots,
                                registry=obs.registry if obs else None)
         metrics.start()
-        # obs plumbing resolved before the loop: JSONL snapshot cadence,
-        # jax.profiler window capture, and the live queue/inflight gauges
+        # obs plumbing resolved before the loop: JSONL snapshot cadence
+        # and the live queue/inflight gauges
         metrics_path = obs.config.metrics_path if obs else None
         metrics_every = obs.config.metrics_every if obs else 1
-        profile_left = obs.config.profile_windows \
-            if obs and obs.config.profile_dir else 0
-        profile_on = False
         if obs:
             g_queue = obs.registry.gauge(
                 "serve_queue_depth", "requests waiting in the scheduler")
@@ -1275,117 +1278,108 @@ class ServeEngine:
 
         try:
             while True:
-                # ---- admission: refill freed slots at the boundary ------
-                with tracer.span("admit", tick=now):
-                    drain_local(now)
-                    free = np.nonzero(lane_req < 0)[0].tolist()
-                    admits = []
-                    for req in self.scheduler.select_window(
-                            len(free), now, k):
-                        need = self._lanes_of(req)   # guided pair = 2/image
-                        lanes, free = free[:need], free[need:]
-                        row = self._admit_host(req, lanes, now, inflight,
-                                               lane_req, lane_img,
-                                               lane_shadow, metrics)
-                        admits.append((req, lanes) + row)
-                    if admits:
-                        state = self._admit_device(state, admits)
-                n_active = int((lane_req >= 0).sum())
-                if obs:
-                    g_queue.set(len(self.scheduler))
-                    g_inflight.set(len(inflight))
-                    tracer.counter("serve_occupancy", lanes=n_active,
-                                   queued=len(self.scheduler))
-                if n_active == 0:
-                    if pending:
-                        # host thinks nothing is live but windows are in
-                        # flight: their retires are what frees lanes
-                        sync_oldest()
-                        if finisher is not None and more_server_work():
-                            finisher.flush(
-                                now,
-                                queue_drained=len(self.scheduler) == 0)
-                        continue
-                    if len(self.scheduler) == 0 and not local_only:
-                        break
-                    # idle: jump to the next arrival instead of spinning —
-                    # recorded, not silent
-                    nxt = [self.scheduler.next_arrival()]
-                    if local_only:
-                        nxt.append(local_only[0].arrival_tick)
-                    target = max(now + 1,
-                                 min(t for t in nxt if t is not None))
-                    metrics.on_idle_gap(target - (now + 1))
+                # one span per iteration: host work no child span covers
+                # is this span's self time
+                with tracer.span("window", tick=now):
+                    # ---- admission: refill freed slots at the boundary ------
+                    with tracer.span("admit", tick=now):
+                        drain_local(now)
+                        free = np.nonzero(lane_req < 0)[0].tolist()
+                        admits = []
+                        for req in self.scheduler.select_window(
+                                len(free), now, k):
+                            need = self._lanes_of(req)   # guided pair = 2/image
+                            lanes, free = free[:need], free[need:]
+                            row = self._admit_host(req, lanes, now, inflight,
+                                                   lane_req, lane_img,
+                                                   lane_shadow, metrics)
+                            admits.append((req, lanes) + row)
+                        if admits:
+                            with tracer.span("admit_device", requests=len(admits)):
+                                state = self._admit_device(state, admits)
+                    n_active = int((lane_req >= 0).sum())
                     if obs:
-                        tracer.instant("idle_jump", from_tick=now,
-                                       to_tick=target)
-                    now = target
+                        g_queue.set(len(self.scheduler))
+                        g_inflight.set(len(inflight))
+                        tracer.counter("serve_occupancy", lanes=n_active,
+                                       queued=len(self.scheduler))
+                    if n_active == 0:
+                        if pending:
+                            # host thinks nothing is live but windows are in
+                            # flight: their retires are what frees lanes
+                            sync_oldest()
+                            if finisher is not None and more_server_work():
+                                finisher.flush(
+                                    now,
+                                    queue_drained=len(self.scheduler) == 0)
+                            continue
+                        if len(self.scheduler) == 0 and not local_only:
+                            break
+                        # idle: jump to the next arrival instead of spinning —
+                        # recorded, not silent
+                        nxt = [self.scheduler.next_arrival()]
+                        if local_only:
+                            nxt.append(local_only[0].arrival_tick)
+                        target = max(now + 1,
+                                     min(t for t in nxt if t is not None))
+                        metrics.on_idle_gap(target - (now + 1))
+                        if obs:
+                            tracer.instant("idle_jump", from_tick=now,
+                                           to_tick=target)
+                        now = target
+                        if now > max_ticks:
+                            raise RuntimeError(
+                                f"engine exceeded liveness bound ({max_ticks} "
+                                f"ticks) with {len(self.scheduler)} queued / 0 "
+                                "in-flight — scheduler starvation?")
+                        continue
+                    # ---- fragmentation + occupancy-by-class telemetry -------
+                    # free lanes entering a window WHILE arrived demand waits
+                    # are fragmentation: the scheduler could not shape the
+                    # queue into them (ragged frees vs batch>1 heads).  The
+                    # class mix is what wave packing homogenizes.
+                    mix: Dict[str, int] = {}
+                    for rec in inflight.values():
+                        if rec["remaining"]:
+                            mix[rec["cls"]] = mix.get(rec["cls"], 0) \
+                                + rec["remaining"]
+                    starved = any(r.arrival_tick <= now
+                                  for r in self.scheduler._queue)
+                    metrics.on_window_mix(mix, self.slots - n_active, starved,
+                                          k)
+                    # ---- ONE dispatch runs k fused ticks over every lane ----
+                    with tracer.span("dispatch", tick=now, lanes=n_active):
+                        state, done_seq = self._tick(state, self.server_params,
+                                                     self._menu)
+                    # exact per-tick occupancy is recovered from this window's
+                    # done stack at sync time (on_window_exact), so the
+                    # dispatch only records the window-start count + the refs
+                    pending.append((done_seq, state["x"], now, n_active))
+                    if obs and admits:
+                        for req, *_ in admits:
+                            obs.request(req.req_id, "first_tick", tick=now)
+                    now += k
+                    # ---- drain the pipeline down to async_depth - 1 ---------
+                    # (async_depth=1: block right here — the synchronous loop)
+                    while len(pending) >= self.async_depth:
+                        sync_oldest()
+                    if finisher is not None and more_server_work():
+                        # boundary hand-off: requests whose last lane retired
+                        # in the syncs above are packed and dispatched NOW,
+                        # while server windows are in flight or about to be —
+                        # this dispatch is the overlap the trace proves.  At
+                        # the LAST boundary (no server work left) staged
+                        # requests fall through to the post-loop drain
+                        # instead, so overlap_frac only counts finish time
+                        # that truly shared the loop with server compute
+                        finisher.flush(now,
+                                       queue_drained=len(self.scheduler) == 0)
                     if now > max_ticks:
                         raise RuntimeError(
                             f"engine exceeded liveness bound ({max_ticks} "
-                            f"ticks) with {len(self.scheduler)} queued / 0 "
-                            "in-flight — scheduler starvation?")
-                    continue
-                # ---- fragmentation + occupancy-by-class telemetry -------
-                # free lanes entering a window WHILE arrived demand waits
-                # are fragmentation: the scheduler could not shape the
-                # queue into them (ragged frees vs batch>1 heads).  The
-                # class mix is what wave packing homogenizes.
-                mix: Dict[str, int] = {}
-                for rec in inflight.values():
-                    if rec["remaining"]:
-                        mix[rec["cls"]] = mix.get(rec["cls"], 0) \
-                            + rec["remaining"]
-                starved = any(r.arrival_tick <= now
-                              for r in self.scheduler._queue)
-                metrics.on_window_mix(mix, self.slots - n_active, starved,
-                                      k)
-                # ---- ONE dispatch runs k fused ticks over every lane ----
-                if profile_left and not profile_on:
-                    # NOT `import jax.profiler` — that would bind `jax` as
-                    # a LOCAL of _serve_server and shadow the module import
-                    from jax import profiler as _profiler
-                    _profiler.start_trace(obs.config.profile_dir)
-                    profile_on = True
-                with tracer.span("dispatch", tick=now, lanes=n_active):
-                    state, done_seq = self._tick(state, self.server_params,
-                                                 self._menu)
-                # exact per-tick occupancy is recovered from this window's
-                # done stack at sync time (on_window_exact), so the
-                # dispatch only records the window-start count + the refs
-                pending.append((done_seq, state["x"], now, n_active))
-                if profile_on:
-                    profile_left -= 1
-                    if profile_left <= 0:
-                        jax.block_until_ready(done_seq)
-                        from jax import profiler as _profiler
-                        _profiler.stop_trace()
-                        profile_on = False
-                if obs and admits:
-                    for req, *_ in admits:
-                        obs.request(req.req_id, "first_tick", tick=now)
-                now += k
-                # ---- drain the pipeline down to async_depth - 1 ---------
-                # (async_depth=1: block right here — the synchronous loop)
-                while len(pending) >= self.async_depth:
-                    sync_oldest()
-                if finisher is not None and more_server_work():
-                    # boundary hand-off: requests whose last lane retired
-                    # in the syncs above are packed and dispatched NOW,
-                    # while server windows are in flight or about to be —
-                    # this dispatch is the overlap the trace proves.  At
-                    # the LAST boundary (no server work left) staged
-                    # requests fall through to the post-loop drain
-                    # instead, so overlap_frac only counts finish time
-                    # that truly shared the loop with server compute
-                    finisher.flush(now,
-                                   queue_drained=len(self.scheduler) == 0)
-                if now > max_ticks:
-                    raise RuntimeError(
-                        f"engine exceeded liveness bound ({max_ticks} "
-                        f"ticks) with {len(self.scheduler)} queued / "
-                        f"{int((lane_req >= 0).sum())} in-flight — "
-                        "scheduler starvation?")
+                            f"ticks) with {len(self.scheduler)} queued / "
+                            f"{int((lane_req >= 0).sum())} in-flight — "
+                            "scheduler starvation?")
         finally:
             self._serving = False
             # the hook closes over THIS call's completions dict — a stale
@@ -1439,12 +1433,18 @@ class ServeEngine:
     # (`_FinishPipeline`) packs each window boundary's freshly-retired
     # requests and defers the sync behind `finish_async_depth`.
     # ------------------------------------------------------------------
-    def _pack_finish(self, comps: List[Completion], client_stack):
+    def _pack_finish(self, comps: List[Completion], client_stack,
+                     metrics: Optional[ServeMetrics] = None):
         """Group the lanes of ``comps`` by ``client_idx`` (compacted to
         the clients present, padded to the widest group) and dispatch ONE
         ``self._finish`` program — each client's group steps against its
         own param row with no per-lane stack gather; padding lanes ride
         the loop masked (they pay model FLOPs but no param traffic).
+        ``metrics`` (when given) counts the wave: its requests and lanes,
+        the client lane-steps dispatched (every padded lane of every
+        present client runs the wave's shared fori bound, the largest
+        ``K - cut`` of its valid lanes) and the useful ones (each valid
+        lane's own ``K - cut``).
 
         Returns ``(x0_ref, placement)`` WITHOUT blocking on the device:
         ``x0_ref`` is the in-flight ``(n_present, width, *image)`` result
@@ -1456,47 +1456,54 @@ class ServeEngine:
         into pack calls yields bitwise-identical x0 rows."""
         assert comps
         n_clients = jax.tree.leaves(client_stack)[0].shape[0]
-        by_client: Dict[int, List] = {}
-        for comp in comps:
-            r = comp.request
-            assert 0 <= r.client_idx < n_clients, \
-                f"request {r.req_id} names client {r.client_idx}; stack " \
-                f"holds {n_clients}"
-            cut = self._effective_cut(r)
-            K = self._sampler_of(r).K
-            tid = self._traj_ids[r.sampler]
-            for i in range(r.batch):
-                by_client.setdefault(r.client_idx, []).append(
-                    (comp, i, cut, K, tid))
-        # compact to the clients that actually have lanes (their param rows
-        # gathered ONCE, not per lane per step) so idle clients cost nothing
-        present = sorted(by_client)
-        groups = [by_client[ci] for ci in present]
-        stack_used = self._gather_stack(client_stack, tuple(present))
-        # width is padded UP to the next power of two: the widest group
-        # tracks the traffic mix, and an exact width would hand
-        # ``self._finish`` a fresh (n_present, width) shape almost every
-        # call — a jit recompile per request batch.  Pow-2 buckets bound
-        # the cache at O(log slots) entries per n_present; padding lanes
-        # ride the loop masked (valid=False), so per-lane outputs are
-        # unchanged (cache growth asserted in tests/test_admission.py).
-        width = max(len(g) for g in groups)
-        width = 1 << (width - 1).bit_length()
-        shp = (len(present), width)
-        x = np.zeros(shp + self.image_shape, np.float32)
-        pos = np.zeros(shp, np.int32)
-        end = np.zeros(shp, np.int32)
-        traj = np.zeros(shp, np.int32)
-        keys = np.zeros(shp + (2,), np.uint32)
-        valid = np.zeros(shp, bool)
-        placement = []
-        for ci, g in enumerate(groups):
-            for j, (comp, i, cut, K, tid) in enumerate(g):
-                x[ci, j] = comp.x_mid[i]
-                pos[ci, j], end[ci, j], traj[ci, j] = cut, K, tid
-                keys[ci, j] = comp.k_cli[i]
-                valid[ci, j] = True
-                placement.append((comp, i, ci, j))
+        with self.obs.tracer.span("finish_pack"):
+            by_client: Dict[int, List] = {}
+            for comp in comps:
+                r = comp.request
+                assert 0 <= r.client_idx < n_clients, \
+                    f"request {r.req_id} names client {r.client_idx}; stack " \
+                    f"holds {n_clients}"
+                cut = self._effective_cut(r)
+                K = self._sampler_of(r).K
+                tid = self._traj_ids[r.sampler]
+                for i in range(r.batch):
+                    by_client.setdefault(r.client_idx, []).append(
+                        (comp, i, cut, K, tid))
+            # compact to the clients that actually have lanes (their param rows
+            # gathered ONCE, not per lane per step) so idle clients cost nothing
+            present = sorted(by_client)
+            groups = [by_client[ci] for ci in present]
+            stack_used = self._gather_stack(client_stack, tuple(present))
+            # width is padded UP to the next power of two: the widest group
+            # tracks the traffic mix, and an exact width would hand
+            # ``self._finish`` a fresh (n_present, width) shape almost every
+            # call — a jit recompile per request batch.  Pow-2 buckets bound
+            # the cache at O(log slots) entries per n_present; padding lanes
+            # ride the loop masked (valid=False), so per-lane outputs are
+            # unchanged (cache growth asserted in tests/test_admission.py).
+            width = max(len(g) for g in groups)
+            width = 1 << (width - 1).bit_length()
+            shp = (len(present), width)
+            x = np.zeros(shp + self.image_shape, np.float32)
+            pos = np.zeros(shp, np.int32)
+            end = np.zeros(shp, np.int32)
+            traj = np.zeros(shp, np.int32)
+            keys = np.zeros(shp + (2,), np.uint32)
+            valid = np.zeros(shp, bool)
+            placement = []
+            for ci, g in enumerate(groups):
+                for j, (comp, i, cut, K, tid) in enumerate(g):
+                    x[ci, j] = comp.x_mid[i]
+                    pos[ci, j], end[ci, j], traj[ci, j] = cut, K, tid
+                    keys[ci, j] = comp.k_cli[i]
+                    valid[ci, j] = True
+                    placement.append((comp, i, ci, j))
+        if metrics is not None:
+            steps = [K - cut for g in groups for _, _, cut, K, _ in g]
+            metrics.on_finish_dispatch(
+                len(comps), len(placement),
+                lane_steps=len(present) * width * max(steps),
+                useful_lane_steps=sum(steps))
         x0_ref = self._finish(stack_used, self._menu, x, pos, end, traj,
                               keys, valid)
         return x0_ref, placement
@@ -1520,7 +1527,8 @@ class ServeEngine:
         completions: fills ``Completion.x0``, flips ``client_finished``,
         and records the ``client_finished`` timeline stage ONCE per
         request.  Returns the completions it closed."""
-        x0 = np.asarray(x0_ref)                  # blocks here
+        with self.obs.tracer.span("finish_wait"):
+            x0 = np.asarray(x0_ref)              # blocks here
         finished: List[Completion] = []
         for comp, img, ci, j in placement:
             if comp.x0 is None:

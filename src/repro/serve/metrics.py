@@ -47,6 +47,8 @@ class ServeMetrics:
         self._lags: List[int] = []              # retire boundary - exact tick
         self._finish_batches = 0                # streamed client-finish calls
         self._finish_lanes = 0
+        self._finish_lane_steps = 0             # padded lanes x fori bound
+        self._finish_useful_lane_steps = 0      # each valid lane's K - cut
         # heterogeneous-traffic telemetry (on_window_mix): slot-ticks per
         # trajectory class, and slot-ticks that sat EMPTY while arrived
         # demand waited in the queue (fragmentation)
@@ -154,13 +156,24 @@ class ServeMetrics:
                 "serve_idle_ticks_total",
                 "ticks skipped with no lane in flight").inc(gap)
 
-    def on_finish_dispatch(self, n_requests: int, lanes: int) -> None:
+    def on_finish_dispatch(self, n_requests: int, lanes: int,
+                           lane_steps: int, useful_lane_steps: int) -> None:
         """One streamed client-finish batch dispatched (finish_mode=
         "stream"): ``n_requests`` freshly-retired requests, grouped by
         client and padded, handed to the finisher program while server
-        windows may still be in flight."""
+        windows may still be in flight.  ``lane_steps`` is what the wave
+        computes (clients x padded width x its shared step bound),
+        ``useful_lane_steps`` what its valid lanes need."""
         self._finish_batches += 1
         self._finish_lanes += lanes
+        self._finish_lane_steps += lane_steps
+        self._finish_useful_lane_steps += useful_lane_steps
+        steps = self.registry.counter(
+            "serve_finish_lane_steps_total",
+            "client lane-steps of the finisher, dispatched (padding "
+            "included) and useful", labels=("kind",))
+        steps.labels(kind="dispatched").inc(lane_steps)
+        steps.labels(kind="useful").inc(useful_lane_steps)
         self.registry.counter(
             "serve_finish_batches_total",
             "streamed client-finish batches dispatched").inc()
@@ -287,6 +300,9 @@ class ServeMetrics:
                 self.capacity * self._mix_ticks)
             out["occupancy_by_class"] = dict(
                 sorted(self._occ_by_class.items()))
+        if self._finish_batches:
+            out["finish_lane_steps"] = self._finish_lane_steps
+            out["finish_useful_lane_steps"] = self._finish_useful_lane_steps
         if self._lags:
             lags = np.array(self._lags, np.float64)
             out["boundary_lag_mean"] = float(lags.mean())

@@ -3,7 +3,10 @@ singletons, the ServeMetrics regressions (auto-start _now, one CutPlan per
 request, rejects-only admission summary), exact vs window-start-approximate
 utilization, and the engine/trainer end-to-end obs integration (obs off ==
 obs on bitwise; Chrome trace-event schema; one dispatch span per window;
-per-request lifecycles with exact finish ticks)."""
+per-request lifecycles with exact finish ticks; the spans on a
+``jax.profiler`` capture's host plane; the layer scopes in the compiled
+programs; the finisher's lane-step counters)."""
+import glob
 import json
 import os
 import sys
@@ -125,6 +128,20 @@ class TestTracer:
                              "ts": 0.0}])
         with pytest.raises(AssertionError):
             validate_events([{"ph": "i", "pid": 0, "tid": 0, "ts": 0.0}])
+
+    def test_span_annotates_the_profile(self, monkeypatch):
+        import repro.obs.trace as trace_mod
+        names = []
+        real = trace_mod.TraceAnnotation
+        monkeypatch.setattr(trace_mod, "TraceAnnotation",
+                            lambda name: names.append(name) or real(name))
+        tr = Tracer()
+        with tr.span("dispatch"):
+            with tr.span("round", cat="train"):
+                pass
+        assert names == ["serve.dispatch", "train.round"]
+        assert [e["name"] for e in tr.events() if e["ph"] == "X"] == \
+            ["round", "dispatch"]
 
     def test_null_tracer_is_free_and_falsy(self):
         assert not NULL_TRACER and isinstance(NULL_TRACER, NullTracer)
@@ -270,8 +287,6 @@ class TestObservability:
     def test_config_validation(self):
         with pytest.raises(AssertionError):
             ObsConfig(metrics_every=0)
-        with pytest.raises(AssertionError):
-            ObsConfig(profile_windows=0)
 
 
 # ---------------------------------------------------------------------------
@@ -390,6 +405,12 @@ def world():
     return cosine_schedule(T), _init_fn(jax.random.PRNGKey(0))
 
 
+def _client_stack(n):
+    from repro.optim import adamw
+    return adamw.tree_stack(
+        [_init_fn(kk) for kk in jax.random.split(jax.random.PRNGKey(1), n)])
+
+
 def _engine(world, obs, **kw):
     sched, server = world
     kw.setdefault("slots", 4)
@@ -445,12 +466,8 @@ class TestEngineObs:
             assert 0 <= ret["tick"] - ret["exact_tick"] <= k - 1
 
     def test_client_finished_stage_lands(self, world):
-        from repro.optim import adamw
-        stack = adamw.tree_stack(
-            [_init_fn(kk) for kk in
-             jax.random.split(jax.random.PRNGKey(1), 2)])
         res = _engine(world, ObsConfig(trace=False)).serve(
-            _requests(4), stack)
+            _requests(4), _client_stack(2))
         for rid, tl in res.timelines.items():
             assert tl[-1]["stage"] == "client_finished"
             assert res.completions[rid].client_finished
@@ -468,6 +485,107 @@ class TestEngineObs:
                 "serve_active_lanes"} <= names
         retired = lines[-1]["metrics"]["serve_retired_total"]
         assert retired["series"][0]["value"] == res.summary["served"]
+
+    def test_profile_holds_one_dispatch_per_window(self, world, tmp_path):
+        """Served under ``jax.profiler.trace``, the host plane holds the
+        engine's spans: one ``serve.dispatch`` per dispatched window,
+        each inside a ``serve.window``, with the loop's other phases
+        nested in windows too."""
+        from jax.profiler import ProfileData
+        eng = _engine(world, ObsConfig(trace=True, timelines=False))
+        stack = _client_stack(2)
+        eng.serve(_requests(6), stack)               # compile outside
+        with jax.profiler.trace(str(tmp_path)):
+            res = eng.serve(_requests(6), stack)
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        spans = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for e in line.events:
+                        if e.name.startswith("serve."):
+                            spans.setdefault(e.name, []).append(
+                                (e.start_ns, e.start_ns + e.duration_ns))
+        windows, dispatch = spans["serve.window"], spans["serve.dispatch"]
+        assert len(dispatch) == res.summary["windows"] > 0
+
+        def inside(span):
+            return [w for w in windows
+                    if w[0] <= span[0] and span[1] <= w[1]]
+        assert all(len(inside(d)) == 1 for d in dispatch)
+        assert all(sum(w[0] <= d[0] and d[1] <= w[1] for d in dispatch)
+                   <= 1 for w in windows)
+        for name in ("serve.admit", "serve.admit_device", "serve.sync_wait",
+                     "serve.retire", "serve.retire_rows"):
+            assert spans[name] and all(inside(s) for s in spans[name]), name
+        for name in ("serve.enqueue", "serve.finish_drain",
+                     "serve.client_finish_dispatch", "serve.finish_pack",
+                     "serve.client_finish_sync", "serve.finish_wait"):
+            assert spans[name], name
+
+    def test_obs_off_builds_no_annotation(self, world, monkeypatch):
+        import repro.obs.trace as trace_mod
+        built = []
+        real = trace_mod.TraceAnnotation
+        monkeypatch.setattr(trace_mod, "TraceAnnotation",
+                            lambda name: built.append(name) or real(name))
+        stack = _client_stack(2)
+        off = _engine(world, None).serve(_requests(6), stack)
+        assert built == []
+        on = _engine(world, ObsConfig(trace=True)).serve(_requests(6), stack)
+        assert "serve.window" in built and "serve.finish_wait" in built
+        assert set(on.completions) == set(off.completions)
+        for rid, comp in off.completions.items():
+            np.testing.assert_array_equal(on.completions[rid].x_mid,
+                                          comp.x_mid)
+            np.testing.assert_array_equal(on.completions[rid].x0, comp.x0)
+
+    def test_compiled_programs_carry_layer_scopes(self, world):
+        """The window and the finisher both run the lane tick, so both
+        compiled programs name the U-Net, the noise draw and the step in
+        their ops' ``op_name`` metadata."""
+        eng = _engine(world, None)
+        sched, server = world
+        stack = _client_stack(2)
+        window = eng._tick.lower(eng._init_state(), server,
+                                 eng._menu).compile().as_text()
+        shp = (2, 2)
+        finish = eng._finish.lower(
+            stack, eng._menu, np.zeros(shp + SHAPE, np.float32),
+            np.zeros(shp, np.int32), np.full(shp, 5, np.int32),
+            np.zeros(shp, np.int32), np.zeros(shp + (2,), np.uint32),
+            np.ones(shp, bool)).compile().as_text()
+        for text in (window, finish):
+            names = [ln.split('op_name="', 1)[1].split('"', 1)[0]
+                     for ln in text.splitlines() if 'op_name="' in ln]
+            for scope in ("/unet/", "/noise/", "/step/"):
+                assert any(scope in n for n in names), scope
+
+    @pytest.mark.parametrize("batches, clients, dispatched, useful", [
+        # one wave: clients present x pow-2 width x the largest K - cut
+        # (6 steps at c=0.6 on the dense T=10 chain; 3 at c=0.3)
+        ((1, 2), (0, 1), 2 * 2 * 6, 1 * 3 + 2 * 6),
+        ((2, 2), (0, 0), 1 * 4 * 6, 2 * 3 + 2 * 6),
+        ((1, 1), (0, 1), 2 * 1 * 6, 3 + 6),
+    ])
+    def test_finish_lane_step_counters(self, world, batches, clients,
+                                       dispatched, useful):
+        reqs = [Request(req_id=i, key=jax.random.PRNGKey(20 + i), batch=b,
+                        cut_ratio=c, client_idx=ci, arrival_tick=0)
+                for i, (b, c, ci) in enumerate(zip(batches, (0.3, 0.6),
+                                                   clients))]
+        eng = _engine(world, ObsConfig(trace=False))
+        res = eng.serve(reqs, _client_stack(2))
+        s = res.summary
+        assert s["finish_batches"] == 1
+        assert s["finish_lanes"] == sum(batches)
+        assert s["finish_lane_steps"] == dispatched
+        assert s["finish_useful_lane_steps"] == useful
+        series = eng.obs.registry.snapshot()[
+            "serve_finish_lane_steps_total"]["series"]
+        assert {x["labels"]["kind"]: x["value"] for x in series} == \
+            {"dispatched": dispatched, "useful": useful}
 
     def test_scheduler_aging_promotions_in_summary(self, world):
         from repro.serve import make_scheduler
