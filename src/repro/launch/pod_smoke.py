@@ -211,7 +211,8 @@ def main(argv=None):
     if args.clients:
         s = res.summary
         print(f"client finish ({s['finish_mode']}): "
-              f"{s['finish_batches']} batch(es), "
+              f"{s['finish_batches']} batch(es) of "
+              f"{s['finish_programs']} program(s), "
               f"overlap_frac {s['overlap_frac']:.2f}", flush=True)
     if args.trace_out:
         suffix = f".host{args.process_id}" if args.num_processes > 1 else ""

@@ -322,7 +322,8 @@ def main(argv=None):
               f"util {s['utilization_mean']:.2f}", flush=True)
         print(f"client finish ({s['finish_mode']}): "
               f"{s['finish_s'] * 1e3:.1f}ms in {s['finish_batches']} "
-              f"batch(es), overlap_frac {s['overlap_frac']:.2f} "
+              f"batch(es) of {s['finish_programs']} program(s), "
+              f"overlap_frac {s['overlap_frac']:.2f} "
               f"(tail {s['finish_tail_s'] * 1e3:.1f}ms)", flush=True)
         if "fragmentation_frac" in s:
             occ = s.get("occupancy_by_class", {})

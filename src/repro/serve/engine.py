@@ -38,8 +38,9 @@ diffusion analogue of LLM continuous batching:
   each host materializes the cut tensors of its OWNED lanes only
   (``Completion.owned`` marks which rows this host holds).
 * A client-segment finisher completes the remaining trajectory positions
-  for every emitted image under its client's private model, grouped by
-  client — the same shared lane tick under ``fori_loop``.  By default it
+  for every emitted image under its client's private model, one program
+  per (step class, client) — the same shared lane tick under
+  ``fori_loop``.  By default it
   STREAMS (``finish_mode="stream"``): at each window boundary the
   requests whose last lane just retired are packed and dispatched
   asynchronously while the next server scan window is already in flight,
@@ -273,34 +274,32 @@ class _FinishPipeline:
     ``on_retired`` hook) into per-CLASS buckets — class = (trajectory,
     cut), i.e. lanes that run the exact same number of client steps;
     :meth:`flush` COALESCES each bucket until roughly two server
-    windows' worth of lanes are staged, then packs a WAVE from it into
-    one grouped finish program and dispatches it ASYNCHRONOUSLY — the
-    next server scan window is already in flight.  The wave discipline
-    is where streaming beats the monolithic drain pass on WORK, not just
-    on overlap: drain's single batch runs every lane to the GLOBAL max
-    step count (a cheap strided-DDIM lane pays the dense-DDPM bound,
-    masked but still computing), while a step-homogeneous wave's shared
-    fori bound is exact.  The buckets are load-bearing precisely
-    BECAUSE arrival is streamed: expensive lanes trickle in a few per
-    window, so any policy that mixes classes per wave (even one that
-    step-sorts the staged pool) seeds nearly every wave with a fresh
+    windows' worth of lanes are staged, then packs a WAVE from it and
+    dispatches it ASYNCHRONOUSLY — the next server scan window is
+    already in flight.  A wave is step-homogeneous AND split by client:
+    :meth:`ServeEngine._pack_finish` runs one program per client present
+    over that client's one-row param stack, so no model call is grouped
+    across clients, each program is padded to its own client's pow-2
+    width (two lanes at least), and its fori bound is its lanes' exact
+    ``K - cut``.  The
+    buckets are load-bearing precisely BECAUSE arrival is streamed:
+    expensive lanes trickle in a few per window, so any policy that
+    mixes classes per wave seeds nearly every wave with a fresh
     long-step lane and re-pays the global bound wave after wave.
     Waves are wide (``2 * slots`` lanes) because each finish dispatch
     also carries a fixed host-pack + program-launch cost that dwarfs a
     few lanes' compute: a per-boundary trickle of 2-4 lanes would be
-    pure overhead, and even slot-width waves pay that toll twice as
-    often for the same lane-steps.  Batches
-    already in flight are reaped WITHOUT blocking as soon as the device
-    reports them ready; the host only blocks once
-    ``finish_async_depth`` batches are in flight.  :meth:`drain` closes
-    the tail after the server queue empties; everything before that
-    tail overlapped server compute, so the summary reports
-    ``overlap_frac = 1 - tail_s / finish_s``
-    (:func:`repro.serve.metrics.finish_summary`).
+    pure overhead.  Waves already in flight are reaped WITHOUT blocking
+    as soon as the device reports every program of the wave ready; the
+    host only blocks once ``finish_async_depth`` waves are in flight.
+    :meth:`drain` closes the tail after the server queue empties, one
+    class at a time; everything before that tail overlapped server
+    compute, so the summary reports ``overlap_frac = 1 - tail_s /
+    finish_s`` (:func:`repro.serve.metrics.finish_summary`).
 
     Bitwise identical to ``ServeEngine._finish_clients`` (the post-drain
     reference): per-lane finish numerics depend only on (param row,
-    x_mid, pos, end, traj, key) — group composition, wave partition,
+    x_mid, pos, end, traj, key) — program composition, wave partition,
     coalescing cadence, pow-2 padding, and the shared fori bound are all
     masked/latched out — gated in ``benchmarks.run --only
     finisher_overlap``."""
@@ -316,15 +315,16 @@ class _FinishPipeline:
         # (host pack + launch + sync), narrow enough that waves still
         # interleave with in-flight windows
         self._wave_lanes = max(1, 2 * engine.slots)
-        # step-class buckets: (traj id, cut, K) -> list of (steps, comp).
+        # step-class buckets: (traj id, cut, K) -> list of completions.
         # The class is a REQUEST property (every lane of a request shares
         # its trajectory and cut), so buckets never split a completion.
         self._ready: Dict[tuple, List] = {}
         self._staged: Dict[tuple, int] = {}    # staged lanes per class
-        # in-flight finish batches, oldest first:
-        # (x0 device ref, placement, dispatch tick)
+        # in-flight finish waves, oldest first: ([(x0 device ref,
+        # placement) per program], dispatch tick)
         self._pending: collections.deque = collections.deque()
         self.batches = 0
+        self.programs = 0
         self.lanes = 0
         self.host_s = 0.0    # total host time inside the finish path
         self.tail_s = 0.0    # the post-drain (non-overlapped) stretch
@@ -337,7 +337,7 @@ class _FinishPipeline:
         cut = self._eng._effective_cut(r)
         K = self._eng._sampler_of(r).K
         key = (self._eng._traj_ids[r.sampler], cut, K)
-        self._ready.setdefault(key, []).append((K - cut, comp))
+        self._ready.setdefault(key, []).append(comp)
         self._staged[key] = self._staged.get(key, 0) + r.batch
 
     def _take_wave(self, key) -> List[Completion]:
@@ -345,7 +345,7 @@ class _FinishPipeline:
         remainder stays staged for the next flush/drain)."""
         bucket, taken, lanes = self._ready[key], [], 0
         while bucket and lanes < self._wave_lanes:
-            _, comp = bucket.pop()
+            comp = bucket.pop()
             taken.append(comp)
             lanes += comp.request.batch
         if not bucket:
@@ -360,24 +360,28 @@ class _FinishPipeline:
         with self._eng.obs.tracer.span(
                 "client_finish_dispatch", tick=now, requests=len(comps),
                 lanes=n_lanes):
-            self._pending.append(
-                self._eng._pack_finish(comps, self._stack, self._metrics)
-                + (now,))
+            wave = self._eng._pack_finish(comps, self._stack, self._metrics)
+            self._pending.append((wave, now))
         self.batches += 1
+        self.programs += len(wave)
         self.lanes += n_lanes
 
     def _sync_oldest(self, now: int) -> None:
-        x0_ref, placement, disp_tick = self._pending.popleft()
+        wave, disp_tick = self._pending.popleft()
         with self._eng.obs.tracer.span(
                 "client_finish_sync", tick=now, dispatch_tick=disp_tick,
-                lanes=len(placement)):
-            self._eng._scatter_finish(x0_ref, placement)
+                lanes=sum(len(p) for _, p in wave)):
+            for x0_ref, placement in wave:
+                self._eng._scatter_finish(x0_ref, placement)
+
+    def _oldest_ready(self) -> bool:
+        return all(_device_ready(ref) for ref, _ in self._pending[0][0])
 
     def flush(self, now: int, queue_drained: bool = False) -> None:
         """One boundary's hand-off: reap (without blocking) every
-        in-flight batch the device has already finished, then dispatch a
+        in-flight wave the device has already finished, then dispatch a
         wave from every class bucket that coalesced one, and drain the
-        pipeline down to ``depth - 1`` batches in flight (depth 1 = sync
+        pipeline down to ``depth - 1`` waves in flight (depth 1 = sync
         right here, the synchronous finisher — the dispatch itself is
         still async w.r.t. the server window already queued on the
         device).  Once the admission queue is empty (``queue_drained``)
@@ -388,7 +392,7 @@ class _FinishPipeline:
             return
         t0 = time.perf_counter()
         with self._eng.obs.tracer.span("finish_flush", tick=now):
-            while self._pending and _device_ready(self._pending[0][0]):
+            while self._pending and self._oldest_ready():
                 self._sync_oldest(now)
             floor = self._wave_lanes // 2 if queue_drained else self._wave_lanes
             for key in [k for k, n in self._staged.items() if n >= floor]:
@@ -400,26 +404,18 @@ class _FinishPipeline:
     def drain(self, now: int) -> None:
         """Close the tail after the server loop: whatever is still staged
         or in flight syncs here — the only stretch of the stream finisher
-        that does NOT overlap server windows.  Leftover sub-wave classes
-        merge step-sorted so each tail batch's fori bound stays close to
-        its lanes' true step counts — with the whole leftover population
-        in hand, sorting CAN bound the mix (unlike in-loop, where
-        streamed arrivals would poison sorted waves)."""
+        that does NOT overlap server windows.  Each leftover class bucket
+        ships as its own wave(s), shortest segment first, so every tail
+        program's fori bound is its lanes' exact ``K - cut``; every wave
+        is dispatched before the first sync, keeping the device queue
+        full."""
         if not self._ready and not self._pending:
             return
         t0 = time.perf_counter()
         with self._eng.obs.tracer.span("finish_drain", tick=now):
-            rest = sorted((item for b in self._ready.values() for item in b),
-                          key=lambda sc: -sc[0])
-            self._ready.clear()
-            self._staged.clear()
-            while rest:
-                comps, lanes = [], 0
-                while rest and lanes < self._wave_lanes:
-                    _, comp = rest.pop(0)
-                    comps.append(comp)
-                    lanes += comp.request.batch
-                self._dispatch(comps, now)
+            for key in sorted(self._ready, key=lambda k: k[2] - k[1]):
+                while key in self._ready:
+                    self._dispatch(self._take_wave(key), now)
             while self._pending:
                 self._sync_oldest(now)
         dt = time.perf_counter() - t0
@@ -428,7 +424,8 @@ class _FinishPipeline:
 
     def summary(self) -> Dict:
         return finish_summary("stream", self.host_s, self.tail_s,
-                              batches=self.batches, lanes=self.lanes)
+                              batches=self.batches, programs=self.programs,
+                              lanes=self.lanes)
 
 
 class ServeEngine:
@@ -727,11 +724,11 @@ class ServeEngine:
 
     def _make_finish(self):
         def finish(client_stack, menu, x, pos, end, traj, keys, valid):
-            # lanes arrive GROUPED BY CLIENT: leading axis = client, second
-            # = (padded) lanes of that client.  vmap pairs each client's
-            # param row with its lane group positionally — each step is one
-            # batched model call per client, with NO per-lane gather of a
-            # full private-model copy from the stack.
+            # leading axis = client, second = (padded) lanes of that
+            # client.  The engine passes ONE client's row (_pack_finish),
+            # so the vmap lowers to plain convolutions over that client's
+            # lanes; over n rows it would lower every convolution to a
+            # grouped one (feature_group_count = n).
             n_steps = jnp.max(jnp.where(valid, end - pos, 0))
             # the client segment is ALWAYS unguided — every finisher lane
             # is its own pair (solo ⇒ raw eps even on a guided sampler's
@@ -1435,27 +1432,31 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def _pack_finish(self, comps: List[Completion], client_stack,
                      metrics: Optional[ServeMetrics] = None):
-        """Group the lanes of ``comps`` by ``client_idx`` (compacted to
-        the clients present, padded to the widest group) and dispatch ONE
-        ``self._finish`` program — each client's group steps against its
-        own param row with no per-lane stack gather; padding lanes ride
-        the loop masked (they pay model FLOPs but no param traffic).
-        ``metrics`` (when given) counts the wave: its requests and lanes,
-        the client lane-steps dispatched (every padded lane of every
-        present client runs the wave's shared fori bound, the largest
-        ``K - cut`` of its valid lanes) and the useful ones (each valid
-        lane's own ``K - cut``).
+        """Split the lanes of ``comps`` by ``client_idx`` and dispatch ONE
+        ``self._finish`` program per client present, over that client's
+        cached one-row param stack: each program's model calls are plain
+        (ungrouped) convolutions over the client's own lanes, padded to
+        the pow-2 of that client's lane count, two at least (padding
+        lanes ride the loop masked).  Each program's fori bound is the largest
+        ``K - cut`` of its own lanes, so a wave of one step class runs
+        every valid lane to exactly its own segment.  ``metrics`` (when
+        given) counts the wave: its requests, lanes and programs, the
+        client lane-steps dispatched (Σ over its programs of padded
+        width × fori bound) and the useful ones (each valid lane's own
+        ``K - cut``).
 
-        Returns ``(x0_ref, placement)`` WITHOUT blocking on the device:
-        ``x0_ref`` is the in-flight ``(n_present, width, *image)`` result
-        and ``placement`` maps its rows back to completion rows as
-        ``(comp, img, ci, j)`` — hand both to :meth:`_scatter_finish`.
-        Per-lane outputs are independent of group composition: lanes past
-        their cut latch bitwise (the shared lane tick's passthrough) and
-        the fori bound is a masked max, so ANY partition of completions
-        into pack calls yields bitwise-identical x0 rows."""
+        Returns the wave's ``[(x0_ref, placement), ...]``, one pair per
+        program, WITHOUT blocking on the device: ``x0_ref`` is the
+        in-flight ``(1, width, *image)`` result and ``placement`` maps its
+        rows back to completion rows as ``(comp, img, j)`` — hand each pair
+        to :meth:`_scatter_finish`.  Per-lane outputs are independent of
+        group composition: lanes past their cut latch bitwise (the shared
+        lane tick's passthrough) and the fori bound is a masked max, so
+        ANY partition of completions into pack calls yields
+        bitwise-identical x0 rows."""
         assert comps
         n_clients = jax.tree.leaves(client_stack)[0].shape[0]
+        wave, lane_steps, useful = [], 0, 0
         with self.obs.tracer.span("finish_pack"):
             by_client: Dict[int, List] = {}
             for comp in comps:
@@ -1469,49 +1470,52 @@ class ServeEngine:
                 for i in range(r.batch):
                     by_client.setdefault(r.client_idx, []).append(
                         (comp, i, cut, K, tid))
-            # compact to the clients that actually have lanes (their param rows
-            # gathered ONCE, not per lane per step) so idle clients cost nothing
-            present = sorted(by_client)
-            groups = [by_client[ci] for ci in present]
-            stack_used = self._gather_stack(client_stack, tuple(present))
-            # width is padded UP to the next power of two: the widest group
-            # tracks the traffic mix, and an exact width would hand
-            # ``self._finish`` a fresh (n_present, width) shape almost every
-            # call — a jit recompile per request batch.  Pow-2 buckets bound
-            # the cache at O(log slots) entries per n_present; padding lanes
-            # ride the loop masked (valid=False), so per-lane outputs are
-            # unchanged (cache growth asserted in tests/test_admission.py).
-            width = max(len(g) for g in groups)
-            width = 1 << (width - 1).bit_length()
-            shp = (len(present), width)
-            x = np.zeros(shp + self.image_shape, np.float32)
-            pos = np.zeros(shp, np.int32)
-            end = np.zeros(shp, np.int32)
-            traj = np.zeros(shp, np.int32)
-            keys = np.zeros(shp + (2,), np.uint32)
-            valid = np.zeros(shp, bool)
-            placement = []
-            for ci, g in enumerate(groups):
+            for ci in sorted(by_client):
+                g = by_client[ci]
+                # width is padded UP to the next power of two: an exact
+                # width would hand ``self._finish`` a fresh (1, width)
+                # shape almost every call — a jit recompile per request
+                # batch.  Pow-2 buckets bound the cache at O(log slots)
+                # entries; padding lanes ride the loop masked
+                # (valid=False), so per-lane outputs are unchanged (cache
+                # growth asserted in tests/test_admission.py).  Two lanes
+                # at least: XLA:CPU lowers a one-row matmul to a
+                # matrix-vector product that rounds differently, which
+                # would tie a lane's bits to its program's width.
+                width = max(2, 1 << (len(g) - 1).bit_length())
+                shp = (1, width)
+                x = np.zeros(shp + self.image_shape, np.float32)
+                pos = np.zeros(shp, np.int32)
+                end = np.zeros(shp, np.int32)
+                traj = np.zeros(shp, np.int32)
+                keys = np.zeros(shp + (2,), np.uint32)
+                valid = np.zeros(shp, bool)
+                placement = []
                 for j, (comp, i, cut, K, tid) in enumerate(g):
-                    x[ci, j] = comp.x_mid[i]
-                    pos[ci, j], end[ci, j], traj[ci, j] = cut, K, tid
-                    keys[ci, j] = comp.k_cli[i]
-                    valid[ci, j] = True
-                    placement.append((comp, i, ci, j))
+                    x[0, j] = comp.x_mid[i]
+                    pos[0, j], end[0, j], traj[0, j] = cut, K, tid
+                    keys[0, j] = comp.k_cli[i]
+                    valid[0, j] = True
+                    placement.append((comp, i, j))
+                steps = [K - cut for _, _, cut, K, _ in g]
+                lane_steps += width * max(steps)
+                useful += sum(steps)
+                x0_ref = self._finish(self._gather_stack(client_stack, (ci,)),
+                                      self._menu, x, pos, end, traj, keys,
+                                      valid)
+                wave.append((x0_ref, placement))
         if metrics is not None:
-            steps = [K - cut for g in groups for _, _, cut, K, _ in g]
             metrics.on_finish_dispatch(
-                len(comps), len(placement),
-                lane_steps=len(present) * width * max(steps),
-                useful_lane_steps=sum(steps))
-        x0_ref = self._finish(stack_used, self._menu, x, pos, end, traj,
-                              keys, valid)
-        return x0_ref, placement
+                len(comps), sum(len(p) for _, p in wave),
+                lane_steps=lane_steps, useful_lane_steps=useful,
+                programs=len(wave))
+        return wave
 
     def _gather_stack(self, client_stack, present: tuple):
-        """The compacted client param stack for one ``present`` set,
-        cached — streamed waves hit the same set every dispatch, and the
-        eager gather is pure host overhead on the hot path.  The cache entry pins the source stack
+        """The client param stack compacted to the rows ``present`` (one
+        client's row, for a finish program), cached — streamed waves hit
+        the same rows every dispatch, and the eager gather is pure host
+        overhead on the hot path.  The cache entry pins the source stack
         so an ``id()`` reuse after GC can never alias a stale gather."""
         hit = self._stack_cache.get((id(client_stack), present))
         if hit is not None and hit[0] is client_stack:
@@ -1523,35 +1527,40 @@ class ServeEngine:
         return gathered
 
     def _scatter_finish(self, x0_ref, placement) -> List[Completion]:
-        """Block on one packed finish batch and scatter its rows into the
+        """Block on one finish program and scatter its rows into the
         completions: fills ``Completion.x0``, flips ``client_finished``,
         and records the ``client_finished`` timeline stage ONCE per
-        request.  Returns the completions it closed."""
+        request (a request's lanes share its client, so one program
+        carries them all).  Returns the completions it closed."""
         with self.obs.tracer.span("finish_wait"):
             x0 = np.asarray(x0_ref)              # blocks here
         finished: List[Completion] = []
-        for comp, img, ci, j in placement:
+        for comp, img, j in placement:
             if comp.x0 is None:
                 comp.x0 = np.zeros((comp.request.batch,) + self.image_shape,
                                    np.float32)
                 finished.append(comp)
-            comp.x0[img] = x0[ci, j]
+            comp.x0[img] = x0[0, j]
         for comp in finished:
             comp.client_finished = True
             self.obs.request(comp.request.req_id, "client_finished")
         return finished
 
-    def _finish_clients(self, result: ServeResult, client_stack) -> None:
+    def _finish_clients(self, result: ServeResult, client_stack) -> int:
         """Post-drain client finish — the REFERENCE implementation the
         streamed path is gated bitwise against (``benchmarks.run --only
-        finisher_overlap``): every completion packed into ONE masked
-        program after the server queue drained.  Fills ``Completion.x0``
-        in place and flips ``client_finished``."""
+        finisher_overlap``): every completion packed into ONE wave (one
+        program per client) after the server queue drained.  Fills
+        ``Completion.x0`` in place and flips ``client_finished``; returns
+        the number of finish programs dispatched."""
         order = sorted(result.completions)
         if not order:
-            return
-        self._scatter_finish(*self._pack_finish(
-            [result.completions[rid] for rid in order], client_stack))
+            return 0
+        wave = self._pack_finish([result.completions[rid] for rid in order],
+                                 client_stack)
+        for x0_ref, placement in wave:
+            self._scatter_finish(x0_ref, placement)
+        return len(wave)
 
     def serve(self, requests: List[Request], client_stack=None,
               max_ticks: Optional[int] = None) -> ServeResult:
@@ -1581,7 +1590,7 @@ class ServeEngine:
             t0 = time.perf_counter()
             with self.obs.tracer.span("finish_clients",
                                       requests=len(result.completions)):
-                self._finish_clients(result, client_stack)
+                programs = self._finish_clients(result, client_stack)
             finish_s = time.perf_counter() - t0
             # drain mode: the finish ran AFTER the loop's wall timer
             # stopped, so it is added to the wall and throughput is
@@ -1590,7 +1599,7 @@ class ServeEngine:
             s = result.summary
             s.update(finish_summary(
                 "drain", finish_s,
-                batches=1 if result.completions else 0,
+                batches=1 if result.completions else 0, programs=programs,
                 lanes=sum(c.request.batch
                           for c in result.completions.values())))
             s["finish_async_depth"] = self.finish_async_depth

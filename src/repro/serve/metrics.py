@@ -157,13 +157,15 @@ class ServeMetrics:
                 "ticks skipped with no lane in flight").inc(gap)
 
     def on_finish_dispatch(self, n_requests: int, lanes: int,
-                           lane_steps: int, useful_lane_steps: int) -> None:
-        """One streamed client-finish batch dispatched (finish_mode=
-        "stream"): ``n_requests`` freshly-retired requests, grouped by
-        client and padded, handed to the finisher program while server
-        windows may still be in flight.  ``lane_steps`` is what the wave
-        computes (clients x padded width x its shared step bound),
-        ``useful_lane_steps`` what its valid lanes need."""
+                           lane_steps: int, useful_lane_steps: int,
+                           programs: int) -> None:
+        """One streamed client-finish wave dispatched (finish_mode=
+        "stream"): ``n_requests`` freshly-retired requests, split by
+        client into ``programs`` padded finish programs, handed to the
+        device while server windows may still be in flight.
+        ``lane_steps`` is what the wave computes (Σ over its programs of
+        padded width x that program's step bound), ``useful_lane_steps``
+        what its valid lanes need."""
         self._finish_batches += 1
         self._finish_lanes += lanes
         self._finish_lane_steps += lane_steps
@@ -177,6 +179,10 @@ class ServeMetrics:
         self.registry.counter(
             "serve_finish_batches_total",
             "streamed client-finish batches dispatched").inc()
+        self.registry.counter(
+            "serve_finish_programs_total",
+            "per-client finish programs in the streamed batches").inc(
+                programs)
         self.registry.counter(
             "serve_finish_lanes_total",
             "lanes handed to the streaming client finisher").inc(lanes)
@@ -314,7 +320,8 @@ class ServeMetrics:
 
 
 def finish_summary(mode: str, finish_s: float, tail_s: float = 0.0,
-                   batches: int = 0, lanes: int = 0) -> Dict:
+                   batches: int = 0, lanes: int = 0,
+                   programs: int = 0) -> Dict:
     """Overlap-aware accounting for the client-finish segment, merged
     into the serve summary by the engine.
 
@@ -339,6 +346,7 @@ def finish_summary(mode: str, finish_s: float, tail_s: float = 0.0,
         "finish_tail_s": tail_s,
         "overlap_frac": float(min(1.0, max(0.0, overlap))),
         "finish_batches": batches,
+        "finish_programs": programs,
         "finish_lanes": lanes,
     }
 

@@ -403,16 +403,29 @@ def test_assert_same_menu_passes_on_equal_menus():
 def test_finisher_jit_cache_stable_under_width_churn(world):
     """Widths 3 and 4 land in the same pow-2 bucket: ONE finisher compile
     for both traffic mixes, and outputs still match the per-lane
-    reference (padding lanes are masked out)."""
+    reference (padding lanes are masked out).  Every finish program runs
+    over one client's row, so the cache holds only one-row shapes, even
+    for a wave that spans three clients."""
     sched, server, stack, _ = world
     eng = _engine(world, None)
-    base = eng._finish._cache_size()
+    jitted, shapes = eng._finish, []
+
+    def recorded(client_stack, menu, x, *rest):
+        shapes.append(tuple(x.shape[:2]))
+        return jitted(client_stack, menu, x, *rest)
+    eng._finish = recorded
+    base = jitted._cache_size()
     r3 = _req(0, 0.5, sampler="ddpm", batch=3, client_idx=1)
     r4 = _req(1, 0.5, sampler="ddpm", batch=4, client_idx=1)
     res3 = eng.serve([r3], stack)
-    assert eng._finish._cache_size() == base + 1
+    assert jitted._cache_size() == base + 1
     res4 = eng.serve([r4], stack)
-    assert eng._finish._cache_size() == base + 1   # width 3 and 4 -> pad 4
+    assert jitted._cache_size() == base + 1   # width 3 and 4 -> pad 4
+    eng.serve([_req(2, 0.5, sampler="ddpm", batch=3, client_idx=0),
+               _req(3, 0.5, sampler="ddpm", batch=1, client_idx=1),
+               _req(4, 0.5, sampler="ddpm", batch=4, client_idx=2)], stack)
+    assert sorted(set(shapes)) == [(1, 2), (1, 4)]
+    assert jitted._cache_size() == base + 2
     server_fn = functools.partial(_apply_fn, server)
     client_fn = functools.partial(_apply_fn, adamw.tree_unstack(stack, 1))
     for res, r in ((res3, r3), (res4, r4)):

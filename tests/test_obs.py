@@ -562,30 +562,43 @@ class TestEngineObs:
             for scope in ("/unet/", "/noise/", "/step/"):
                 assert any(scope in n for n in names), scope
 
-    @pytest.mark.parametrize("batches, clients, dispatched, useful", [
-        # one wave: clients present x pow-2 width x the largest K - cut
-        # (6 steps at c=0.6 on the dense T=10 chain; 3 at c=0.3)
-        ((1, 2), (0, 1), 2 * 2 * 6, 1 * 3 + 2 * 6),
-        ((2, 2), (0, 0), 1 * 4 * 6, 2 * 3 + 2 * 6),
-        ((1, 1), (0, 1), 2 * 1 * 6, 3 + 6),
-    ])
-    def test_finish_lane_step_counters(self, world, batches, clients,
-                                       dispatched, useful):
+    @pytest.mark.parametrize(
+        "batches, cuts, clients, waves, programs, dispatched, useful", [
+            # a wave per step class (6 client steps at c=0.6 on the dense
+            # T=10 chain; 3 at c=0.3), a program per client in each, padded
+            # to that client's pow-2 width (two lanes at least) and run for
+            # its class's steps
+            ((1, 2), (0.3, 0.6), (0, 1), 2, 2, 2 * 3 + 2 * 6,
+             1 * 3 + 2 * 6),
+            ((2, 2), (0.3, 0.6), (0, 0), 2, 2, 2 * 3 + 2 * 6,
+             2 * 3 + 2 * 6),
+            ((1, 1), (0.3, 0.6), (0, 1), 2, 2, 2 * 3 + 2 * 6, 3 + 6),
+            # two classes x two clients: client 0's three c=0.6 lanes pad
+            # to 4; one program a wave over both clients at the widest
+            # client's width (the rule before) ran 2 x 4 x 6 + 2 x 2 x 3
+            # = 60
+            ((1, 2, 3, 1), (0.3, 0.3, 0.6, 0.6), (0, 1, 0, 1), 2, 4,
+             2 * 3 + 2 * 3 + 4 * 6 + 2 * 6, 1 * 3 + 2 * 3 + 3 * 6 + 1 * 6),
+        ])
+    def test_finish_lane_step_counters(self, world, batches, cuts, clients,
+                                       waves, programs, dispatched, useful):
         reqs = [Request(req_id=i, key=jax.random.PRNGKey(20 + i), batch=b,
                         cut_ratio=c, client_idx=ci, arrival_tick=0)
-                for i, (b, c, ci) in enumerate(zip(batches, (0.3, 0.6),
-                                                   clients))]
+                for i, (b, c, ci) in enumerate(zip(batches, cuts, clients))]
         eng = _engine(world, ObsConfig(trace=False))
         res = eng.serve(reqs, _client_stack(2))
         s = res.summary
-        assert s["finish_batches"] == 1
+        assert s["finish_batches"] == waves
+        assert s["finish_programs"] == programs
         assert s["finish_lanes"] == sum(batches)
         assert s["finish_lane_steps"] == dispatched
         assert s["finish_useful_lane_steps"] == useful
-        series = eng.obs.registry.snapshot()[
-            "serve_finish_lane_steps_total"]["series"]
+        snap = eng.obs.registry.snapshot()
+        series = snap["serve_finish_lane_steps_total"]["series"]
         assert {x["labels"]["kind"]: x["value"] for x in series} == \
             {"dispatched": dispatched, "useful": useful}
+        assert snap["serve_finish_programs_total"]["series"][0]["value"] \
+            == programs
 
     def test_scheduler_aging_promotions_in_summary(self, world):
         from repro.serve import make_scheduler

@@ -831,6 +831,48 @@ def test_stream_finish_bitwise_under_admission_gate(models,
                                       err_msg=f"x0 {rid}")
 
 
+def test_stream_tail_packs_one_program_per_class_and_client(models):
+    """A drain tail of 3 step classes x 3 clients: every class ships as
+    its own wave and every client of it as its own one-row program,
+    padded to that (class, client) group's pow-2 width (two lanes at
+    least) and run for the class's own steps — and x0 stays bitwise the
+    drain reference's."""
+    sched, server, stack = models
+    classes = [("ddpm", 0.25), ("ddpm", 0.75), ("ddim6", 0.75)]
+    # 6 lanes a class: under the halved wave floor (slots lanes), so no
+    # class ships before the tail
+    reqs = [Request(req_id=3 * c + ci,
+                    key=jax.random.fold_in(jax.random.PRNGKey(77), 3 * c + ci),
+                    batch=1 + (c + ci) % 3, cut_ratio=cut, client_idx=ci,
+                    arrival_tick=0, sampler=name)
+            for c, (name, cut) in enumerate(classes) for ci in range(3)]
+    ref = _engine(sched, server, slots=8, samplers=_mixed_menu(),
+                  finish_mode="drain").serve(reqs, stack)
+    eng = _engine(sched, server, slots=8, samplers=_mixed_menu(),
+                  finish_mode="stream")
+    jitted, shapes = eng._finish, []
+
+    def recorded(client_stack, menu, x, *rest):
+        assert jax.tree.leaves(client_stack)[0].shape[0] == 1
+        shapes.append(tuple(x.shape[:2]))
+        return jitted(client_stack, menu, x, *rest)
+    eng._finish = recorded
+    res = eng.serve(reqs, stack)
+    pow2 = [max(2, 1 << (r.batch - 1).bit_length()) for r in reqs]
+    assert sorted(shapes) == sorted((1, w) for w in pow2)
+    steps = [eng._sampler_of(r).K - eng._effective_cut(r) for r in reqs]
+    assert len(set(steps)) == len(classes)
+    s = res.summary
+    assert (s["finish_batches"], s["finish_programs"]) == (3, 9)
+    assert s["finish_lane_steps"] == sum(w * n for w, n in zip(pow2, steps))
+    assert s["finish_useful_lane_steps"] == sum(
+        r.batch * n for r, n in zip(reqs, steps))
+    assert set(res.completions) == set(ref.completions)
+    for rid, comp in ref.completions.items():
+        np.testing.assert_array_equal(res.completions[rid].x0, comp.x0,
+                                      err_msg=f"x0 {rid}")
+
+
 def test_drain_local_batches_one_draw_per_boundary(models):
     """Local-only (c=1.0) requests due at the same boundary share ONE
     vmapped x_T draw — and each lane's slice is bitwise the independent
