@@ -1,5 +1,6 @@
-"""One cell's run: build the engine from the configuration through the
-program's public constructor, drive closed generation jobs through
+"""One cell's run: build the engine from the configuration, through its
+family adapter (``bench/families/``) and the program's public
+constructor, drive closed generation jobs through
 ``ServeEngine.serve`` with the streaming finisher, and record what the
 metrics read.
 
@@ -11,12 +12,14 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import inspect
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchlib import spec
 from benchlib import traffic as traffic_mod
 
 # stage names of the program's per-request timelines
@@ -80,27 +83,34 @@ class Probe:
     finish programs.  When ``traced`` it marks the host side of each call
     with a ``TraceAnnotation`` for the profile.  While ``warming``, every
     finish program runs with all its lanes marked invalid: it compiles or
-    loads at its shape but steps no lane."""
+    loads at its shape but steps no lane.  The finisher's arguments are
+    found by name (``x``, ``valid``), so the program may add or reorder
+    the others."""
 
     def __init__(self, eng, traced: bool = False):
         self.windows = 0
         self.finish_shapes = set()
         self.warming = False
         tick, finish = eng._tick, eng._finish
+        finish_sig = inspect.signature(finish)
         ann = (jax.profiler.TraceAnnotation if traced
                else lambda name: contextlib.nullcontext())
 
-        def counted_tick(state, params, menu):
+        @functools.wraps(tick)
+        def counted_tick(*args, **kwargs):
             self.windows += 1
             with ann("bench:dispatch_window"):
-                return tick(state, params, menu)
+                return tick(*args, **kwargs)
 
-        def counted_finish(stack, menu, x, pos, end, traj, keys, valid):
-            self.finish_shapes.add(tuple(x.shape[:2]))
+        @functools.wraps(finish)
+        def counted_finish(*args, **kwargs):
+            bound = finish_sig.bind(*args, **kwargs)
+            a = bound.arguments
+            self.finish_shapes.add(tuple(a["x"].shape[:2]))
             if self.warming:
-                valid = np.zeros_like(valid)
+                a["valid"] = np.zeros_like(a["valid"])
             with ann("bench:dispatch_finish"):
-                return finish(stack, menu, x, pos, end, traj, keys, valid)
+                return finish(*bound.args, **bound.kwargs)
 
         eng._tick = counted_tick
         eng._finish = counted_finish
@@ -111,10 +121,8 @@ class Cell:
 
     def __init__(self, config: dict, traffic: dict, seed: int,
                  traced: bool = False):
-        from repro.configs.base import UNetConfig
         from repro.diffusion.sampler import make_sampler
         from repro.diffusion.schedule import get_schedule
-        from repro.models import unet
         from repro.obs import ObsConfig
         from repro.serve.engine import EngineConfig, ServeEngine
         from repro.serve.scheduler import make_scheduler
@@ -122,26 +130,24 @@ class Cell:
         self.config, self.traffic, self.seed = config, traffic, seed
         m = config["model"]
         self.model = m
-        self.ucfg = UNetConfig(**{k: tuple(v) if isinstance(v, list) else v
-                                  for k, v in m.items()})
+        family = spec.family(config)
+        self.num_classes = family.num_classes(m)
         e = config["engine"]
         self.slots, self.n_clients = e["slots"], e["clients"]
-        self.image_shape = (m["image_size"], m["image_size"],
-                            m["in_channels"])
+        self.image_shape = tuple(family.image_shape(m))
         T = config["schedule"]["T"]
         self.samplers = {
             name: make_sampler(T, s["family"], s.get("num_steps", 0),
-                               s.get("eta", 1.0))
+                               s.get("eta", 1.0), guidance=s.get("guidance"))
             for name, s in traffic["samplers"].items()}
         pol = traffic["scheduler"]
         self.scheduler = make_scheduler(pol["policy"], T,
                                         samplers=self.samplers,
                                         pack=pol["pack"])
-        ucfg = self.ucfg
+        init = family.init(m)
 
         @jax.jit
         def make_weights(k_server, k_clients):
-            init = functools.partial(unet.init_params, cfg=ucfg)
             return init(k_server), jax.vmap(init)(k_clients)
 
         k_server, k_clients = weight_keys(seed, self.n_clients)
@@ -149,7 +155,7 @@ class Cell:
                                                              k_clients)
         self.engine = ServeEngine(EngineConfig(
             sched=get_schedule(config["schedule"]["name"], T),
-            apply_fn=functools.partial(_apply, cfg=ucfg),
+            apply_fn=family.apply_fn(m), num_classes=self.num_classes,
             image_shape=self.image_shape, slots=self.slots,
             scheduler=self.scheduler, clip=e["clip"],
             samplers=self.samplers,
@@ -159,7 +165,8 @@ class Cell:
             obs=ObsConfig(trace=False, timelines=True)), self.server_params)
         self.probe = Probe(self.engine, traced=traced)
         self.specs = traffic_mod.composition(traffic, self.slots,
-                                             self.n_clients)
+                                             self.n_clients,
+                                             self.num_classes)
         self.images_per_job = traffic_mod.job_images(self.specs)
         self.lane_steps = traffic_mod.lane_steps(config, traffic, self.specs)
         self._job_keys = jax.jit(functools.partial(_job_keys,
@@ -178,7 +185,7 @@ class Cell:
         keys = np.asarray(self._job_keys(self._base, job))
         return [Request(req_id=i, key=keys[i], batch=s.batch,
                         cut_ratio=s.cut_ratio, client_idx=s.client,
-                        arrival_tick=0, sampler=s.sampler)
+                        arrival_tick=0, sampler=s.sampler, label=s.label)
                 for i, s in enumerate(self.specs)]
 
     def run_job(self, job: int, keep_outputs: bool = True) -> JobRecord:
@@ -199,11 +206,6 @@ class Cell:
     def free(self):
         """Drop every device buffer this cell holds."""
         del self.engine, self.server_params, self.client_stack
-
-
-def _apply(params, x, t, cfg):
-    from repro.models import unet
-    return unet.forward(params, x, t, cfg)
 
 
 def _job_keys(base, job, n):

@@ -36,9 +36,12 @@ def reference_outputs(config: dict, traffic: dict, sample: List[dict],
     """(x_c, x_0) of every image of the sampled requests, from the plain
     reference at ``precision``; ``weights_dtype`` rounds its weights
     through that type first (a lower-precision control where the backend
-    ignores the matmul precision, as the CPU does)."""
+    ignores the matmul precision, as the CPU does).  On a configuration
+    that takes class labels, each lane is replayed with its request's
+    label and its sampler's guidance weight (None: unguided)."""
     ref = spec.reference_module(config["reference"])
     m = config["model"]
+    conditional = spec.family(config).num_classes(m) > 0
     sch = ref.schedule(config["schedule"]["name"], config["schedule"]["T"])
     T = config["schedule"]["T"]
     k_server, k_clients = weight_keys(seed, config["engine"]["clients"])
@@ -57,10 +60,12 @@ def reference_outputs(config: dict, traffic: dict, sample: List[dict],
             cut = ref.cut_position(ts, T, r.cut_ratio)
             if r.client_idx not in clients:
                 clients[r.client_idx] = init(k_clients[r.client_idx])
+            cond = ({"label": r.label, "guidance": s.get("guidance")}
+                    if conditional else {})
             for i in range(r.batch):
                 out.append(ref.replay_lane(server, clients[r.client_idx],
                                            r.key, i, m, ts, coefs, cut,
-                                           clip))
+                                           clip, **cond))
     return out
 
 

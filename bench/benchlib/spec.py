@@ -1,7 +1,7 @@
 """Find a cell's pieces by name: its entry in BENCHMARK.json, its
-configuration file, its traffic file, its limits file, the plain
-reference of its configuration and the readers of its per-layer
-metrics."""
+configuration file, its traffic file, its limits file, the family
+adapter and plain reference of its configuration and the readers of its
+per-layer metrics."""
 from __future__ import annotations
 
 import importlib.util
@@ -10,6 +10,7 @@ from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+FAMILIES = BENCH / "families"
 
 
 def load_json(path: Path) -> dict:
@@ -57,3 +58,10 @@ def reader(metric: str):
 def reference_module(name: str):
     """The plain reference ``bench/reference/<name>.py``."""
     return _module(BENCH / "reference" / f"{name}.py", f"bench_ref_{name}")
+
+
+def family(config: dict):
+    """The family adapter ``<FAMILIES>/<name>.py`` of a configuration,
+    named by its ``family`` key (``"unet"`` where absent)."""
+    name = config.get("family", "unet")
+    return _module(FAMILIES / f"{name}.py", f"bench_family_{name}")

@@ -3,10 +3,13 @@
 A traffic file fixes a job: every (sampler, batch, cut) combination in
 turn, in the file's order, until the job holds
 ``job_images_per_slot × slots`` images (the last request is cut to fit).
-Clients are dealt round-robin over that order.  Every seed asks for the
-same job; the seed draws only the weights, the keys and the check's
-sample.  Every request of a job arrives at tick 0, and every job of a run
-repeats the composition with keys of its own.
+Clients, and on a configuration that takes class labels the labels, are
+dealt round-robin over that order.  A sampler may carry ``"guidance": w``
+(classifier-free guidance; its images take a cond and an uncond lane on
+the server).  Every seed asks for the same job; the seed draws only the
+weights, the keys and the check's sample.  Every request of a job arrives
+at tick 0, and every job of a run repeats the composition with keys of
+its own.
 """
 from __future__ import annotations
 
@@ -23,9 +26,11 @@ class Spec:
     cut_ratio: float
     client: int
     sampler: str
+    label: int = 0
 
 
-def composition(traffic: dict, slots: int, clients: int) -> List[Spec]:
+def composition(traffic: dict, slots: int, clients: int,
+                num_classes: int = 0) -> List[Spec]:
     cycle = [(name, b, c)
              for name in traffic["samplers"]
              for b in range(traffic["batch"]["min"],
@@ -36,8 +41,10 @@ def composition(traffic: dict, slots: int, clients: int) -> List[Spec]:
     while total < n_images:
         name, b, c = cycle[len(specs) % len(cycle)]
         b = min(b, n_images - total)
-        specs.append(Spec(batch=b, cut_ratio=c, client=len(specs) % clients,
-                          sampler=name))
+        i = len(specs)
+        specs.append(Spec(batch=b, cut_ratio=c, client=i % clients,
+                          sampler=name,
+                          label=i % num_classes if num_classes else 0))
         total += b
     return specs
 
@@ -50,7 +57,9 @@ def lane_steps(config: dict, traffic: dict,
                specs: List[Spec]) -> Tuple[int, int]:
     """(server, client) lane-steps one job asks for: each image steps from
     the start of its trajectory to its cut on the server and from the cut
-    to the end on its client, by the reference's trajectories and cuts."""
+    to the end on its client, by the reference's trajectories and cuts.
+    A guided image steps two server lanes (cond and uncond) and one
+    client lane."""
     ref = spec.reference_module(config["reference"])
     T = config["schedule"]["T"]
     server = client = 0
@@ -58,6 +67,7 @@ def lane_steps(config: dict, traffic: dict,
         smp = traffic["samplers"][s.sampler]
         ts = ref.timesteps(T, smp["family"], smp.get("num_steps", 0))
         cut = ref.cut_position(ts, T, s.cut_ratio)
-        server += s.batch * cut
+        lanes = 2 if smp.get("guidance") is not None else 1
+        server += s.batch * cut * lanes
         client += s.batch * (len(ts) - cut)
     return server, client

@@ -16,6 +16,22 @@ position, with the serving engine's key derivation:
 
 Server steps run positions [0, cut) under the server weights from k_srv;
 client steps run [cut, K) under the request's client weights from k_cli.
+
+A configuration whose ``model`` has ``num_classes`` > 0 is class
+conditional: a table of ``num_classes + 1`` label embeddings (the last
+row is the null label) is added to the time embedding.  A guided server
+step is classifier-free guidance (Ho & Salimans, arXiv:2207.12598) in the
+GLIDE/DiT form, w = 1 being the conditional model alone:
+
+    eps = eps_u + w * (eps_c - eps_u)
+    eps_c = eps(x, t, label), eps_u = eps(x, t, null label)
+
+with the step's noise drawn from the lane's own key chain.  The protocol
+as this system serves it departs from plain guided sampling in two
+places, both of the protocol and not of the model: an unguided request's
+steps, and every client step of any request, run unguided on the null
+label (the client finishes on its private model without the request's
+label).
 """
 from __future__ import annotations
 
@@ -74,6 +90,9 @@ def init_params(key, m: dict):
          "time_mlp2": {"w": _dense_init(next(ks), (td, td), td),
                        "bias": jnp.zeros((td,), jnp.float32)},
          "conv_in": _conv_init(next(ks), 3, 3, m["in_channels"], ch)}
+    if m.get("num_classes"):
+        p["label_emb"] = _dense_init(next(ks), (m["num_classes"] + 1, td),
+                                     td)
     res, cur, chans = m["image_size"], ch, [ch]
     mults = m["channel_mults"]
     downs = []
@@ -148,8 +167,10 @@ def _attn(x, p, g):
     return x + _conv(o.reshape(b, h, w, c), p["out"])
 
 
-def forward(params, x, t, m: dict):
-    """ε̂ for images x (B, H, W, C) at integer timesteps t (B,)."""
+def forward(params, x, t, m: dict, y=None):
+    """ε̂ for images x (B, H, W, C) at integer timesteps t (B,), under
+    class labels y (B,) where the model is conditional (None: the null
+    label)."""
     g, td = m["norm_groups"], m["time_dim"]
     half = td // 2
     freqs = jnp.exp(-math.log(10_000.0) * jnp.arange(half) / half)
@@ -158,6 +179,11 @@ def forward(params, x, t, m: dict):
     temb = jax.nn.silu(temb @ params["time_mlp1"]["w"]
                        + params["time_mlp1"]["bias"])
     temb = temb @ params["time_mlp2"]["w"] + params["time_mlp2"]["bias"]
+    nc = m.get("num_classes", 0)
+    if nc:
+        if y is None:
+            y = jnp.full(x.shape[:1], nc, jnp.int32)
+        temb = temb + params["label_emb"][y]
     h = _conv(x, params["conv_in"])
     skips = [h]
     for stage in params["downs"]:
@@ -262,9 +288,22 @@ def coefficients(sch: dict, ts, family: str, eta: float) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # one lane's chain, one jitted step per position
 # ---------------------------------------------------------------------------
+def _eps(params, x, t, y, w, m):
+    """ε̂ of one step: the model under label y (None: unconditional), or
+    the guided combine at weight w with the null label as the uncond
+    branch, both branches in one forward."""
+    if w is None:
+        return forward(params, x, t, m, y)
+    null = jnp.full_like(y, m["num_classes"])
+    e = forward(params, jnp.concatenate([x, x]), jnp.concatenate([t, t]),
+                m, jnp.concatenate([y, null]))
+    eps_c, eps_u = e[:1], e[1:]
+    return eps_u + w * (eps_c - eps_u)
+
+
 @functools.partial(jax.jit, static_argnames=("m", "clip"))
-def _step(params, x, t, key, coef, m, clip):
-    eps = forward(params, x, t, dict(m))
+def _step(params, x, t, y, w, key, coef, m, clip):
+    eps = _eps(params, x, t, y, w, dict(m))
     key, k_n = jax.random.split(key)
     z = jax.random.normal(k_n, x.shape[1:], jnp.float32)[None]
     c_eps, ar, sigma, keep = coef[0], coef[1], coef[2], coef[3]
@@ -281,11 +320,21 @@ def _lane_start(req_key, image, shape_probe):
 
 
 def replay_lane(server_params, client_params, req_key, image: int,
-                m: dict, ts, coefs, cut: int, clip: float):
-    """(x_c, x_0) of image ``image`` of a request, replayed alone."""
+                m: dict, ts, coefs, cut: int, clip: float, *,
+                label=None, guidance=None):
+    """(x_c, x_0) of image ``image`` of a request, replayed alone.  On a
+    conditional model, ``guidance`` (a weight, or None for an unguided
+    request) guides the server steps towards ``label``; every other step
+    runs on the null label."""
     frozen = tuple((k, tuple(v) if isinstance(v, list) else v)
                    for k, v in sorted(m.items()))
     shape = (m["image_size"], m["image_size"], m["in_channels"])
+    nc = m.get("num_classes", 0)
+    null = jnp.asarray([nc], jnp.int32) if nc else None
+    if guidance is None:
+        y_srv, w = null, None
+    else:
+        y_srv, w = jnp.asarray([label], jnp.int32), jnp.float32(guidance)
     x, k_srv, k_cli = _lane_start(jnp.asarray(req_key), image,
                                   jnp.zeros(shape, jnp.float32))
     x = x[None]
@@ -294,9 +343,13 @@ def replay_lane(server_params, client_params, req_key, image: int,
         if pos == cut:
             x_c = x
             key = k_cli
-        params = server_params if pos < cut else client_params
-        x, key = _step(params, x, jnp.asarray([ts[pos]], jnp.int32), key,
-                       coefs[pos], frozen, clip)
+        t = jnp.asarray([ts[pos]], jnp.int32)
+        if pos < cut:
+            x, key = _step(server_params, x, t, y_srv, w, key, coefs[pos],
+                           frozen, clip)
+        else:
+            x, key = _step(client_params, x, t, null, None, key,
+                           coefs[pos], frozen, clip)
     if cut == len(ts):
         x_c = x
     return np.asarray(x_c[0]), np.asarray(x[0])

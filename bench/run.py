@@ -7,8 +7,9 @@ A cell is one entry of BENCHMARK.json's ``workloads``: a configuration
 (``bench/configs/<config>.json``) under a traffic mix
 (``bench/traffic/<traffic>.json``).  The run
 
-1. builds the engine through ``EngineConfig``/``ServeEngine`` with
-   weights drawn from ``--seed`` on the device, and serves one warm-up
+1. builds the engine through the configuration's family adapter
+   (``bench/families/<family>.py``) and ``EngineConfig``/``ServeEngine``,
+   with weights drawn from ``--seed`` on the device, and serves one warm-up
    job, which compiles or loads every program the window uses; its
    finish programs step no lane (set-up);
 2. serves whole jobs back to back until ``--seconds`` have passed (the
@@ -127,7 +128,7 @@ def run(args, c=None, require_chip: bool = True) -> dict:
         devs = jax.devices()
     from benchlib import check, trace
     from benchlib.cell import Cell, CompileCounter, p95, peak_bytes
-    from benchlib.yardsticks import peaks, unet_forward_flops
+    from benchlib.yardsticks import peaks
 
     counter = CompileCounter()
     traced = bool(args.trace)
@@ -206,9 +207,11 @@ def run(args, c=None, require_chip: bool = True) -> dict:
             metrics[m["name"]] = {"value": values[m["name"]],
                                   "unit": m["unit"]}
     else:
+        flops_per_forward = spec.family(config).forward_flops(
+            config["model"])
         data = {"config": config, "traffic": traffic, "jobs": jobs,
                 "slice": reduced, "slice_counts": sl.counts,
-                "flops_per_forward": unet_forward_flops(config["model"]),
+                "flops_per_forward": flops_per_forward,
                 "peaks": peaks(d.device_kind)}
         for m in c["per_layer"]:
             v = spec.reader(m["name"])(data)
@@ -219,7 +222,8 @@ def run(args, c=None, require_chip: bool = True) -> dict:
             device["window_s"] = reduced["window_s"]
             result["breakdown"] = {"device_ops": reduced["device_ops"],
                                    "idle_gaps": reduced["idle_gaps"]}
-        log(f"traced job: {json.dumps(sl.counts)}; programs "
+        log(f"traced job: {json.dumps(sl.counts)}; flops_per_forward "
+            f"{flops_per_forward}; programs "
             f"{json.dumps((reduced or {}).get('module_s'))}; kernel "
             f"{json.dumps((reduced or {}).get('kernel'))}")
         log(f"window metrics under tracing: images/s {images / window_s}, "

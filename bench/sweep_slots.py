@@ -1,18 +1,19 @@
-"""Per-image time of a configuration's U-Net forward at several batch
-sizes, on the chip: once it stops falling, more slots stop raising
-images/s and only lengthen each job.
+"""Per-image time of a configuration's model forward (through its family
+adapter, ``bench/families/``) at several batch sizes, on the chip: once
+it stops falling, more slots stop raising images/s and only lengthen
+each job.
 
     python3 bench/sweep_slots.py --config paper_unet --batches 8 32 64 128
 
 One JSON line per batch on standard output: the batch, the seconds per
 image (median of three timings, each of enough calls to span half a
 second), the compile seconds, and the compiled program's argument, output
-and temporary bytes.  Weights and inputs are drawn from ``--seed``.
+and temporary bytes.  Weights and inputs (and class labels, on a
+conditional model) are drawn from ``--seed``.
 """
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import statistics
 import sys
@@ -52,29 +53,31 @@ def main(argv=None) -> int:
     if harness.device_check(jax, 1) is None:
         return 2
     import jax.numpy as jnp
-    from repro.configs.base import UNetConfig
-    from repro.models import unet
 
     m = config["model"]
-    cfg = UNetConfig(**{k: tuple(v) if isinstance(v, list) else v
-                        for k, v in m.items()})
+    family = spec.family(config)
+    nc = family.num_classes(m)
     T = config["schedule"]["T"]
     with jax.default_matmul_precision(config["precision"]):
         key = jax.random.PRNGKey(args.seed)
-        params = jax.jit(functools.partial(unet.init_params, cfg=cfg))(key)
-        fwd = jax.jit(lambda p, x, t: unet.forward(p, x, t, cfg))
+        params = jax.jit(family.init(m))(key)
+        fwd = jax.jit(family.apply_fn(m))
         for b in args.batches:
             kx, kt = jax.random.split(jax.random.fold_in(key, b))
-            x = jax.random.normal(kx, (b, m["image_size"], m["image_size"],
-                                       m["in_channels"]), jnp.float32)
+            x = jax.random.normal(kx, (b,) + tuple(family.image_shape(m)),
+                                  jnp.float32)
             t = jax.random.randint(kt, (b,), 1, T + 1)
+            inputs = (params, x, t)
+            if nc:
+                inputs += (jax.random.randint(jax.random.fold_in(kt, 1),
+                                              (b,), 0, nc + 1),)
             t0 = time.perf_counter()
-            compiled = fwd.lower(params, x, t).compile()
+            compiled = fwd.lower(*inputs).compile()
             compile_s = time.perf_counter() - t0
             mem = compiled.memory_analysis()
             print(json.dumps({
                 "config": args.config, "batch": b,
-                "s_per_image": per_image_s(compiled, (params, x, t), b),
+                "s_per_image": per_image_s(compiled, inputs, b),
                 "compile_s": compile_s,
                 "argument_bytes": mem.argument_size_in_bytes,
                 "output_bytes": mem.output_size_in_bytes,

@@ -31,16 +31,26 @@ def test_step_that_returns_its_state_unchanged(monkeypatch):
 def test_half_of_the_batch_left_out(monkeypatch):
     """The model runs on the first half of each batch of lanes and the
     rest reuse its outputs."""
-    import jax.numpy as jnp
-    from repro.models import unet
-    forward = unet.forward
+    import types
 
-    def half(params, x, t, cfg, y=None):
-        b = x.shape[0]
-        h = max(1, -(-b // 2))
-        eps = forward(params, x[:h], t[:h], cfg)
-        return jnp.concatenate([eps, eps[:b - h]])
-    monkeypatch.setattr(unet, "forward", half)
+    import jax.numpy as jnp
+    from benchlib import spec
+    family = spec.family
+
+    def halved(config):
+        fam = family(config)
+
+        def apply_fn(model):
+            apply = fam.apply_fn(model)
+
+            def half(params, x, t, *y):
+                b = x.shape[0]
+                h = max(1, -(-b // 2))
+                eps = apply(params, x[:h], t[:h], *(v[:h] for v in y))
+                return jnp.concatenate([eps, eps[:b - h]])
+            return half
+        return types.SimpleNamespace(**dict(vars(fam), apply_fn=apply_fn))
+    monkeypatch.setattr(spec, "family", halved)
     assert not _run()["correct"]
 
 
